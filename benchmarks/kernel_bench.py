@@ -189,25 +189,28 @@ def main(reduced: bool = False) -> None:
     from repro.noc.api import Budget, NocProblem
     from repro.noc.api import run as noc_run
 
+    # A TPU belongs to one process, so the process executor is refused
+    # there (repro.dist.worker.ShardPool) and the row is skipped.
+    import jax as _jax
+
     dist_problem = NocProblem(spec=spec_tiny(), traffic="BFS")
-    dist_cfg = {"n_workers": 4, "executor": "process", "iters_max": 2,
-                "n_swaps": 6, "n_link_moves": 6, "max_local_steps": 20}
-    with Timer() as t:
-        dist_res = noc_run(dist_problem, "stage_dist",
-                           budget=Budget(max_evals=400, seed=0),
-                           config=dist_cfg)
-    row("stage_dist_4w", t.dt * 1e6,
-        f"workers=4;process;evals={dist_res.n_evals};"
-        f"pareto={len(dist_res.designs)}")
-    bench["stage_dist_4w_us"] = t.dt * 1e6
+    if _jax.default_backend() != "tpu":
+        dist_cfg = {"n_workers": 4, "executor": "process", "iters_max": 2,
+                    "n_swaps": 6, "n_link_moves": 6, "max_local_steps": 20}
+        with Timer() as t:
+            dist_res = noc_run(dist_problem, "stage_dist",
+                               budget=Budget(max_evals=400, seed=0),
+                               config=dist_cfg)
+        row("stage_dist_4w", t.dt * 1e6,
+            f"workers=4;process;evals={dist_res.n_evals};"
+            f"pareto={len(dist_res.designs)}")
+        bench["stage_dist_4w_us"] = t.dt * 1e6
 
     # shard_map executor (DESIGN.md §12): in-order shards whose evaluator
     # batches run as ONE multi-device program each. On this 1-device CPU
     # container the mesh is trivial — the row tracks the shard_map
     # dispatch overhead vs the serial executor; on a real multi-device
     # host the same row shows the batch-parallel win.
-    import jax as _jax
-
     spmd_cfg = {"n_workers": 2, "executor": "spmd", "iters_max": 2,
                 "n_swaps": 6, "n_link_moves": 6, "max_local_steps": 20}
     with Timer() as t:

@@ -9,7 +9,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     reduced = "--full" not in sys.argv
     # Routing backend for the search benchmarks (fig4/fig8/table2):
     # --backend=jnp|pallas|auto. Validated up front so a typo fails fast
@@ -22,8 +22,10 @@ def main() -> None:
             backend = arg.split("=", 1)[1]
         if arg.startswith("--only="):
             only = set(arg.split("=", 1)[1].split(","))
+    from repro import compile_cache
     from repro.core import routing
     routing.resolve_backend(backend)  # raises ValueError on typos
+    compile_cache.enable()
     print(f"# repro benchmarks (reduced={reduced}, backend={backend})")
     print("name,us_per_call,derived")
     t0 = time.perf_counter()
@@ -40,6 +42,7 @@ def main() -> None:
     if only is not None and (unknown := only - names):
         raise SystemExit(f"--only names unknown modules: {sorted(unknown)}; "
                          f"available: {sorted(names)}")
+    failed = []
     for mod in mods:
         name = mod.__name__.rsplit(".", 1)[-1]
         if only is not None and name not in only:
@@ -48,12 +51,16 @@ def main() -> None:
         kwargs = {"backend": backend} if mod in takes_backend else {}
         try:
             mod.main(reduced=reduced, **kwargs)
-        except Exception as e:  # pragma: no cover — keep the suite running
+        except Exception as e:  # keep the suite running; fail at the end
             print(f"{name},0,ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
         print(f"# {name} took {time.perf_counter()-t:.1f}s", flush=True)
 
     print(f"# total {time.perf_counter()-t0:.1f}s")
+    if failed:
+        print(f"# FAILED modules: {','.join(failed)}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -70,6 +70,18 @@ def make_spmd_mesh():
     return Mesh(np.array(jax.devices()), ("dev",))
 
 
+def batch_pipeline(consts: SpecConsts, backend: str, interpret: bool,
+                   perms, adjs, f):
+    """Cost build → batched APSP on ``backend`` → objective walk for a
+    (B, N) / (B, N, N) candidate batch: the traceable body of one
+    evaluation dispatch (the SPMD path runs it per device shard)."""
+    costs = jax.vmap(partial(design_cost, consts))(adjs)
+    dist, nh = routing.routing_tables_batched(
+        costs, consts.apsp_iters, backend=backend, interpret=interpret)
+    return jax.vmap(partial(evaluate_with_tables, consts),
+                    in_axes=(0, 0, None, 0, 0))(perms, adjs, f, dist, nh)
+
+
 class Evaluator:
     """Jitted batched evaluator for a fixed (spec, traffic) pair.
 
@@ -131,23 +143,16 @@ class Evaluator:
         traffic matrix rides in replicated. Numerically identical to the
         single-device path — sharding the batch axis splits independent
         per-design programs, it reorders no reductions."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        consts, backend, interpret = self.consts, self.backend, self.interpret
-
-        def local_fn(perms, adjs, f):
-            costs = jax.vmap(partial(design_cost, consts))(adjs)
-            dist, nh = routing.routing_tables_batched(
-                costs, consts.apsp_iters, backend=backend,
-                interpret=interpret)
-            return jax.vmap(partial(evaluate_with_tables, consts),
-                            in_axes=(0, 0, None, 0, 0))(
-                perms, adjs, f, dist, nh)
-
+        local_fn = partial(batch_pipeline, self.consts, self.backend,
+                           self.interpret)
         p = P(self.mesh.axis_names[0])
-        return jax.jit(shard_map(local_fn, mesh=self.mesh,
-                                 in_specs=(p, p, P()), out_specs=(p, p)))
+        # The Pallas interpreter cannot trace under the varying-axes check
+        # (its index arithmetic is not marked varying); compiled kernels can.
+        return jax.jit(jax.shard_map(local_fn, mesh=self.mesh,
+                                     in_specs=(p, p, P()), out_specs=(p, p),
+                                     check_vma=not self.interpret))
 
     # ------------------------------------------------------------- single
     def __call__(self, d: Design) -> np.ndarray:

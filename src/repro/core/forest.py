@@ -20,28 +20,20 @@ gathers — with a backend switch mirroring core.routing:
     agrees with numpy up to f32 threshold rounding.
   * ``"pallas"`` — the blocked VMEM-resident traversal kernel in
     kernels/forest (grid over batch blocks, node tensors pinned across the
-    grid). TPU only; ``interpret=True`` runs it on CPU (tests); requesting
-    it on a CPU/GPU host without interpret falls back to jnp with a
-    one-time warning (same contract as core.routing's backend switch —
-    never fail inside jit because of the host platform).
-  * ``"auto"``   — ``"pallas"`` on TPU, ``"jnp"`` on GPU, numpy/jnp by
-    batch size on CPU (DESIGN.md §4.4).
+    grid). It runs only through the Pallas interpreter (``interpret=True``;
+    conformance tests): the TPU compiler refuses its per-tree gathers, so
+    no default path selects it and a request without ``interpret`` raises.
+  * ``"auto"``   — jnp on an accelerator (TPU or GPU), numpy/jnp by batch
+    size on CPU (DESIGN.md §4.4).
 """
 
 from __future__ import annotations
 
-import warnings
 from functools import partial
 
 import numpy as np
 
 FOREST_BACKENDS = ("auto", "numpy", "jnp", "pallas")
-
-_PALLAS_FALLBACK_WARNED = False
-#: set after an on-device kernel failure — resolution then routes every
-#: non-interpret pallas request (including auto-on-TPU) to jnp so one
-#: Mosaic lowering failure cannot crash every subsequent surrogate predict.
-_PALLAS_DISABLED = False
 
 
 def check_forest_backend(backend: str | None, *,
@@ -63,38 +55,28 @@ def resolve_forest_backend(backend: str | None = None,
                            interpret: bool = False) -> str:
     """Resolve ``backend`` (default ``"auto"``) to a concrete one.
 
-    ``auto`` picks the Pallas kernel on TPU and jnp on GPU; on CPU it picks
+    ``auto`` never picks the Pallas kernel. Mosaic cannot lower its gathers
+    for the TPU (``take_along_axis`` of a (T, M) node table by (T, B)
+    pointers: the index shape must equal the operand shape), so on a TPU,
+    as on a GPU, ``auto`` is the jitted jnp traversal. On CPU it picks
     numpy for small (neighborhood-sized) batches, where per-call dispatch
-    dominates, and the jitted jnp traversal for large ones. An explicit
-    ``"pallas"`` on a host without a TPU resolves to ``"jnp"`` with a
-    one-time warning unless ``interpret`` is set (the interpreter runs the
-    kernel anywhere)."""
-    global _PALLAS_FALLBACK_WARNED
+    dominates, and jnp for large ones. An explicit ``"pallas"`` needs
+    ``interpret=True`` (the Pallas interpreter runs the kernel anywhere)
+    and raises ``ValueError`` without it, on every platform."""
     b = backend if backend is not None else "auto"
     check_forest_backend(b)
     if b == "auto":
         import jax
 
-        platform = jax.default_backend()
-        if platform == "tpu":
-            b = "pallas"
-        elif platform == "gpu":
+        if jax.default_backend() != "cpu":
             b = "jnp"
         else:
             b = "numpy" if batch is not None and batch < 512 else "jnp"
     if b == "pallas" and not interpret:
-        import jax
-
-        if _PALLAS_DISABLED:
-            b = "jnp"
-        elif jax.default_backend() != "tpu":
-            if not _PALLAS_FALLBACK_WARNED:
-                warnings.warn(
-                    "forest backend 'pallas' requires a TPU (or "
-                    "interpret=True); falling back to 'jnp' on "
-                    f"{jax.default_backend()!r}", stacklevel=2)
-                _PALLAS_FALLBACK_WARNED = True
-            b = "jnp"
+        raise ValueError(
+            "forest backend 'pallas' runs only through the Pallas "
+            "interpreter (interpret=True): the TPU compiler cannot lower "
+            "its gathers; use 'jnp' or 'auto'")
     return b
 
 
@@ -449,23 +431,7 @@ class RegressionForest:
         bp = -(-b // _forest.BLOCK_B) * _forest.BLOCK_B
         xp = np.zeros((bp, xn.shape[1]), np.float32)
         xp[:b] = xn
-        try:
-            out = _forest.forest_predict(
-                *self._flat_pallas, jnp.asarray(xp),
-                depth=self._flat["depth"], interpret=interpret)[:b]
-        except Exception as e:
-            if interpret:
-                raise
-            # On-device escape hatch: if Mosaic rejects the kernel on real
-            # hardware, disable it for the process and serve the jnp twin —
-            # "auto" must never crash an optimizer run mid-search.
-            global _PALLAS_DISABLED
-            if not _PALLAS_DISABLED:
-                warnings.warn(
-                    "pallas forest kernel failed on this device "
-                    f"({type(e).__name__}: {e}); disabling it and falling "
-                    "back to 'jnp' for the rest of the process",
-                    stacklevel=2)
-                _PALLAS_DISABLED = True
-            return self._predict_jnp(xn)
+        out = _forest.forest_predict(
+            *self._flat_pallas, jnp.asarray(xp),
+            depth=self._flat["depth"], interpret=interpret)[:b]
         return np.asarray(out, np.float64)

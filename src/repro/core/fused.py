@@ -36,7 +36,7 @@ from functools import lru_cache, partial
 import numpy as np
 
 from .features import _batch_consts
-from .forest import RegressionForest, resolve_forest_backend
+from .forest import RegressionForest
 from .problem import Design, NeighborMoves, SystemSpec
 
 META_BACKENDS = ("host", "fused", "fused-pallas")
@@ -254,9 +254,9 @@ class MetaScorer:
     :meth:`score_moves` call is one XLA dispatch over the whole padded
     neighborhood. ``backend="fused-pallas"`` routes the
     normalize→traverse→argmax tail through the Pallas kernel in
-    kernels/stage_fused (TPU, or ``interpret=True`` for CPU testing) with
-    the same on-failure fallback contract as the forest's pallas path —
-    featurization stays jnp either way."""
+    kernels/stage_fused. Like the forest's pallas backend it runs only
+    through the Pallas interpreter (``interpret=True``, CPU conformance
+    tests) and raises without it; featurization stays jnp either way."""
 
     def __init__(self, spec: SystemSpec, model: RegressionForest, *,
                  backend: str = "fused", interpret: bool = False):
@@ -281,10 +281,12 @@ class MetaScorer:
             (self.depth, self.n_trees, self.n_nodes) = model.jnp_tensors()
         self.xm = jnp.asarray(model._xm, jnp.float32)
         self.xs = jnp.asarray(model._xs, jnp.float32)
-        # resolve once: "fused-pallas" off-TPU without interpret falls back
-        # to the jnp tail exactly like forest backend "pallas" does.
-        self.pallas = (backend == "fused-pallas" and resolve_forest_backend(
-            "pallas", interpret=interpret) == "pallas")
+        if backend == "fused-pallas" and not interpret:
+            raise ValueError(
+                "meta backend 'fused-pallas' runs only through the Pallas "
+                "interpreter (interpret=True): the TPU compiler cannot "
+                "lower its forest gathers; use 'fused'")
+        self.pallas = backend == "fused-pallas"
         self.interpret = interpret
         self._pallas_nodes = None
         if self.pallas:
@@ -369,19 +371,12 @@ class MetaScorer:
 
             feats = self._feat_jit(self.c, base_perm, base_lm, scalars,
                                    sa, sb, er, ea)
-            try:
-                vj, j = _sf.score_block_max(
-                    *self._pallas_nodes, self.xm.reshape(1, -1),
-                    self.xs.reshape(1, -1), feats,
-                    np.array([[b]], np.int32), depth=self.depth,
-                    interpret=self.interpret)
-                return int(j), float(vj)
-            except Exception:
-                if self.interpret:
-                    raise
-                # same never-crash-mid-search contract as forest pallas:
-                # fall through to the jnp tail for this and later calls.
-                self.pallas = False
+            vj, j = _sf.score_block_max(
+                *self._pallas_nodes, self.xm.reshape(1, -1),
+                self.xs.reshape(1, -1), feats,
+                np.array([[b]], np.int32), depth=self.depth,
+                interpret=self.interpret)
+            return int(j), float(vj)
         vals = _SCORE_JIT(self.c, self.thrfeat, self.child, self.value,
                           self.xm, self.xs, base_perm, base_lm, scalars,
                           sa, sb, er, ea, depth=self.depth,

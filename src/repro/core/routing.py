@@ -201,11 +201,16 @@ def walk_paths(
         cur = jnp.where(done, cur, nxt)
         return cur, hops, delay, util, visits
 
-    cur0 = src
-    hops0 = jnp.zeros((n, n), jnp.int32)
-    delay0 = jnp.zeros((n, n), jnp.float32)
-    util0 = jnp.zeros((n, n), jnp.float32)
-    visits0 = jnp.zeros((n,), jnp.float32)
+    # The initial carry is derived from ``nh`` so that it has nh's type,
+    # varying manual axes included: under shard_map the body's outputs
+    # vary over the device axis, and a carry of plain constants would not.
+    zero = nh * 0
+    zero_f = zero.astype(jnp.float32)
+    cur0 = src + zero
+    hops0 = zero
+    delay0 = zero_f
+    util0 = zero_f
+    visits0 = zero_f[0]
     cur, hops, delay, util, visits = jax.lax.fori_loop(
         0, max_hops, body, (cur0, hops0, delay0, util0, visits0)
     )

@@ -122,7 +122,8 @@ def _meta_greedy(
     move-apply → featurize → normalize → forest traversal happen inside a
     single jit (core.fused); only the winning move is materialized.
     ``"fused-pallas"`` additionally routes the scoring tail through the
-    kernels/stage_fused Pallas kernel (TPU); ``"host"`` is the legacy
+    kernels/stage_fused Pallas kernel (interpreter only: pass a
+    ``scorer`` built with ``interpret=True``); ``"host"`` is the legacy
     host-featurizing loop. All arms consume the identical rng stream and
     accept with the same strict ``vals[j] > v_curr + 1e-12`` test, so the
     accepted-move sequences agree across backends up to f32-vs-f64 forest

@@ -18,6 +18,9 @@ Executor matrix (DESIGN.md §8):
     ``concurrent.futures.ProcessPoolExecutor`` with the **spawn** start
     method — fork after JAX has initialized its runtime threads can
     deadlock, so children pay a fresh interpreter + import instead.
+    Refused on a TPU host: a chip belongs to one process, so children
+    would contend with the parent for it. The in-process executors carry
+    chip work there.
 ``jax``
     One shard per JAX device, round-robin, each executed under
     ``jax.default_device(dev)`` so its XLA dispatches land on its own
@@ -311,6 +314,13 @@ class ShardPool:
     """
 
     def __init__(self, n_workers: int):
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "executor='process' spawns children that each import JAX, "
+                "and a TPU belongs to one process at a time; on a TPU host "
+                "use an in-process executor ('serial', 'jax' or 'spmd')")
         self.n_workers = max(1, int(n_workers))
         self.rebuilds = 0
         self._pool = self._make()

@@ -20,9 +20,11 @@ VMEM budget: node tensors are (T, M) f32/int32 x 5 (threshold, feature,
 ~16 MiB/core limit for every forest the repo trains.
 
 This module is the ``backend="pallas"`` implementation behind
-core.forest.RegressionForest.predict; ``resolve_forest_backend("auto")``
-selects it on TPU, and ``interpret=True`` runs it through the Pallas
-interpreter on CPU (tests, CI smoke).
+core.forest.RegressionForest.predict, run through the Pallas interpreter
+(``interpret=True``; conformance tests). The TPU compiler refuses its
+gathers: Mosaic lowers ``take_along_axis`` only where the index shape
+equals the operand shape, and here a (T, M) table is indexed by (T, bb)
+pointers. So no default path selects it (``resolve_forest_backend``).
 """
 
 from __future__ import annotations

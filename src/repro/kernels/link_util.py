@@ -4,22 +4,29 @@ The scatter-add formulation (core/routing.walk_paths) is the natural GPU
 port; TPUs have no fast scatter, so this kernel re-expresses the walk as
 dense one-hot linear algebra — the DESIGN.md §4 hardware adaptation:
 
-  for each destination d (grid axis):
-    C_0 = I                      (N sources x N positions, one-hot "cursor")
-    M[v, u] = [nh[v, d] == u]    (next-hop transition matrix, one-hot)
+  for each destination d (grid axis), with the cursor kept transposed
+  (positions x sources) so every operand is a full (N, N) tile or a
+  (1, N) row and no matmul needs a transposed left operand:
+    CT_0 = I                      (N positions x N sources, one-hot)
+    MT[u, v] = [nh[v, d] == u]    (transposed next-hop transition, one-hot)
     per hop t:
-      C_{t+1} = C_t @ M                                (MXU)
-      util   += (C_t * w_t)^T @ C_{t+1}                (MXU; w_t = f masked by done)
-      delay_d += rowsum((C_t @ delay) * C_{t+1})       (MXU + VPU)
-      hops_d  += 1 - done,   visits += w_t @ C_t       (VPU)
+      CT_{t+1} = MT @ CT_t                              (MXU)
+      util    += (CT_t * w_t) @ CT_{t+1}^T              (MXU; w_t = f masked by done)
+      delay_d += colsum((delay^T @ CT_t) * CT_{t+1})    (MXU + VPU)
+      hops_d  += 1 - done,   visits += rowsum(CT_t * w_t)   (VPU)
 
 ``nh`` must be self-absorbing at the destination (nh[d, d] = d), which
 core/routing.next_hop guarantees — finished pairs then accumulate zero
-because w_t is masked by done = C_t[:, d].
+because w_t is masked by done = CT_t[d, :].
 
-All per-destination working state (C, M: N x N f32) lives in VMEM; with
-N = 64 that is 16 KiB per buffer. The destination axis is the (sequential)
-grid; util/visits blocks are revisited and accumulated across it.
+Per-destination inputs and outputs are rows of (N, 1, N) views (column d
+of nh / f, transposed), so every block's last two dims are either whole
+array dims or (1, N) — the TPU tiling rule. All per-destination working
+state (CT, MT: N x N f32) lives in VMEM; with N = 64 that is 16 KiB per
+buffer. The destination axis is the (sequential) grid; util/visits blocks
+are revisited and accumulated across it. Matmuls that carry traffic
+weights run at f32 precision (HIGHEST), the one-hot ones are exact either
+way.
 """
 
 from __future__ import annotations
@@ -32,7 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _walk_kernel(nh_col_ref, f_col_ref, delay_ref, util_ref, hops_ref,
+def _walk_kernel(nh_ref, f_ref, delay_t_ref, util_ref, hops_ref,
                  dsum_ref, visits_ref, *, max_hops: int, n: int):
     d = pl.program_id(0)
 
@@ -41,40 +48,44 @@ def _walk_kernel(nh_col_ref, f_col_ref, delay_ref, util_ref, hops_ref,
         util_ref[...] = jnp.zeros_like(util_ref)
         visits_ref[...] = jnp.zeros_like(visits_ref)
 
-    nh_col = nh_col_ref[...][:, 0]            # (N,) int32: nh[:, d]
-    f_col = f_col_ref[...][:, 0]              # (N,) f32:  f[:, d]
-    delay = delay_ref[...]                    # (N, N)
+    nh_row = nh_ref[0]                 # (1, N) int32: nh[:, d]
+    f_row = f_ref[0]                   # (1, N) f32:  f[:, d]
+    delay_t = delay_t_ref[...]         # (N, N): delay_t[v, u] = delay[u, v]
 
-    iota_u = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    m = (nh_col[:, None] == iota_u).astype(jnp.float32)      # (N, N)
-    c = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == iota_u
-         ).astype(jnp.float32)                               # identity
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    m_t = (rows == nh_row).astype(jnp.float32)                # (N, N)
+    at_d = rows == d
+    hi = jax.lax.Precision.HIGHEST
+    nt = (((1,), (1,)), ((), ()))      # a @ b.T
 
     def body(_, carry):
-        c, util, hops, dsum, visits = carry
-        done = c[:, d]                                        # (N,)
-        w = f_col * (1.0 - done)
-        cn = jnp.dot(c, m, preferred_element_type=jnp.float32)
-        util = util + jnp.dot((c * w[:, None]).T, cn,
-                              preferred_element_type=jnp.float32)
-        step_delay = jnp.sum(
-            jnp.dot(c, delay, preferred_element_type=jnp.float32) * cn, axis=1
-        )
-        dsum = dsum + (1.0 - done) * step_delay
-        hops = hops + (1.0 - done)
-        visits = visits + jnp.dot(w[None, :], c,
-                                  preferred_element_type=jnp.float32)[0]
-        return cn, util, hops, dsum, visits
+        c_t, util, hops, dsum, visits = carry
+        done = jnp.sum(jnp.where(at_d, c_t, 0.0), axis=0, keepdims=True)
+        live = 1.0 - done                                     # (1, N)
+        cw = c_t * (f_row * live)
+        cn_t = jnp.dot(m_t, c_t, preferred_element_type=jnp.float32)
+        util = util + jax.lax.dot_general(
+            cw, cn_t, nt, precision=hi, preferred_element_type=jnp.float32)
+        step = jnp.sum(
+            jnp.dot(delay_t, c_t, precision=hi,
+                    preferred_element_type=jnp.float32) * cn_t,
+            axis=0, keepdims=True)
+        dsum = dsum + live * step
+        hops = hops + live
+        visits = visits + jnp.sum(cw, axis=1, keepdims=True)  # (N, 1)
+        return cn_t, util, hops, dsum, visits
 
-    c, util_acc, hops, dsum, visits_acc = jax.lax.fori_loop(
+    row0 = jnp.zeros((1, n), jnp.float32)
+    _, util_acc, hops, dsum, visits_acc = jax.lax.fori_loop(
         0, max_hops, body,
-        (c, jnp.zeros((n, n), jnp.float32), jnp.zeros((n,), jnp.float32),
-         jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32)),
+        ((rows == cols).astype(jnp.float32), jnp.zeros((n, n), jnp.float32),
+         row0, row0, jnp.zeros((n, 1), jnp.float32)),
     )
     util_ref[...] += util_acc
-    visits_ref[...] += visits_acc[None, :]
-    hops_ref[...] = hops[:, None]
-    dsum_ref[...] = dsum[:, None]
+    visits_ref[...] += visits_acc
+    hops_ref[0] = hops
+    dsum_ref[0] = dsum
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "interpret"))
@@ -90,27 +101,28 @@ def walk_accumulate(
     core/routing.walk_paths (visits includes the destination router)."""
     n = nh.shape[0]
     kernel = functools.partial(_walk_kernel, max_hops=max_hops, n=n)
-    util, hops, dsum, visits = pl.pallas_call(
+    row_d = pl.BlockSpec((1, 1, n), lambda d: (d, 0, 0),
+                         memory_space=pltpu.VMEM)
+    whole = pl.BlockSpec((n, n), lambda d: (0, 0), memory_space=pltpu.VMEM)
+    util, hops_t, dsum_t, visits = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda d: (0, d), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, 1), lambda d: (0, d), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, n), lambda d: (0, 0), memory_space=pltpu.VMEM),
-        ],
+        in_specs=[row_d, row_d, whole],
         out_specs=[
-            pl.BlockSpec((n, n), lambda d: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, 1), lambda d: (0, d), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, 1), lambda d: (0, d), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda d: (0, 0), memory_space=pltpu.VMEM),
+            whole,
+            row_d,
+            row_d,
+            pl.BlockSpec((n, 1), lambda d: (0, 0), memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, n), jnp.float32),   # util (directed)
-            jax.ShapeDtypeStruct((n, n), jnp.float32),   # hops
-            jax.ShapeDtypeStruct((n, n), jnp.float32),   # delay sums
-            jax.ShapeDtypeStruct((1, n), jnp.float32),   # visits
+            jax.ShapeDtypeStruct((n, n), jnp.float32),     # util (directed)
+            jax.ShapeDtypeStruct((n, 1, n), jnp.float32),  # hops, [d, 0, s]
+            jax.ShapeDtypeStruct((n, 1, n), jnp.float32),  # delay sums
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),     # visits
         ],
         interpret=interpret,
-    )(nh.astype(jnp.int32), f.astype(jnp.float32), delay.astype(jnp.float32))
-    visits = visits[0] + jnp.sum(f, axis=0)  # destination router traversal
-    return hops, dsum, util, visits
+    )(nh.astype(jnp.int32).T.reshape(n, 1, n),
+      f.astype(jnp.float32).T.reshape(n, 1, n),
+      delay.astype(jnp.float32).T)
+    visits = visits[:, 0] + jnp.sum(f, axis=0)  # destination router traversal
+    return hops_t[:, 0, :].T, dsum_t[:, 0, :].T, util, visits

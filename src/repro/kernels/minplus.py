@@ -9,17 +9,19 @@ axis accumulating ``minimum`` into the output block (the same revisiting
 pattern as an MXU matmul k-loop, but on the VPU — min of sums has no MXU
 lowering).
 
-Block sizes keep the (bm, bk, bn) broadcast intermediate within VMEM:
-128 x 32 x 128 x 4 B = 2 MiB. The grid is therefore already k-blocked and
-memory-safe at the spec_large/spec_1024 tiers (DESIGN.md §13) — no (N, N, N)
-intermediate ever materializes; the jnp fallback gets the same property from
-``routing.min_plus_blocked`` above ``routing.DENSE_NMAX``.
+Block shapes follow the TPU tiling rule: the last two dims of every block
+are multiples of (8, 128) or the whole (padded) array dim. So up to 128
+tiles one block spans the matrix (N <= 128: bm = bn = bk = N), and above
+it the blocks are 128 x 128 with a 128-wide k step. The (bm, bk, bn)
+broadcast intermediate is at most 128^3 x 4 B = 8 MiB of VMEM. The grid is
+k-blocked at every tier (DESIGN.md §13), so no (N, N, N) intermediate ever
+materializes.
 
 This module is the ``backend="pallas"`` implementation behind
-core.routing.apsp_batched / routing_tables_batched; core.evaluate.Evaluator
-threads its batched candidate APSP through that switch (``"auto"`` selects
-this kernel on TPU, the jnp oracle elsewhere; ``interpret=True`` runs it on
-CPU for tests).
+core.routing.apsp_batched / routing_tables_batched at every tile count;
+core.evaluate.Evaluator threads its batched candidate APSP through that
+switch (``"auto"`` selects this kernel on TPU, the jnp oracle elsewhere;
+``interpret=True`` runs it on CPU for tests).
 """
 
 from __future__ import annotations
@@ -45,27 +47,20 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
     o_ref[0] = jnp.minimum(o_ref[0], cand)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def minplus(
     a: jax.Array,  # (B, N, N)
     b: jax.Array,  # (B, N, N)
     *,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 32,
+    block: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """out[b, i, j] = min_k a[b, i, k] + b[b, k, j]. Pads N with +INF rows
-    (neutral for min-plus) to hardware-aligned tiles."""
+    (neutral for min-plus) up to a multiple of ``block`` when N > ``block``;
+    ``block`` must be a multiple of 128 (the lane width)."""
     bsz, n, _ = a.shape
-    bm, bn, bk = (min(block_m, n), min(block_n, n), min(block_k, n))
-    # Pad to multiples of the block sizes (and >= (8, 128) f32 TPU tiles when
-    # the matrix is large enough to care).
-    def _pad_to(x, m):
-        return (x + m - 1) // m * m
-
-    npad = max(_pad_to(n, bm), _pad_to(n, bn), _pad_to(n, bk))
+    bm = bn = bk = min(block, n)
+    npad = -(-n // bk) * bk
     if npad != n:
         pad = ((0, 0), (0, npad - n), (0, npad - n))
         a = jnp.pad(a, pad, constant_values=INF)
@@ -83,7 +78,11 @@ def minplus(
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b_, i, j, k: (b_, i, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((bsz, npad, npad), jnp.float32),
+        # Under shard_map the output varies over the same mesh axes as
+        # the operands (the batch is sharded, each device runs its shard).
+        out_shape=jax.ShapeDtypeStruct(
+            (bsz, npad, npad), jnp.float32,
+            vma=jax.typeof(a).vma | jax.typeof(b).vma),
         interpret=interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32))
     return out[:, :n, :n]

@@ -1,10 +1,12 @@
 """jit'd public wrappers around the Pallas kernels with backend dispatch.
 
-On TPU the Pallas kernels run compiled; on CPU (this container) the same
-entry points fall back to the pure-jnp references (or interpret mode when
-explicitly requested) so the whole framework runs everywhere. Training uses
-custom_vjp wrappers whose backward pass recomputes via the reference
-formulation (flash-style recompute — no O(S^2) residuals are saved)."""
+``use_kernel=None`` picks the compiled Pallas kernel on TPU and the pure-jnp
+reference elsewhere. ``interpret=True`` runs the kernel through the Pallas
+interpreter on any host (tests). An explicit ``use_kernel=True`` off the TPU
+without ``interpret`` raises: nothing switches to interpret mode on its own.
+Training uses custom_vjp wrappers whose backward pass recomputes via the
+reference formulation (flash-style recompute — no O(S^2) residuals are
+saved)."""
 
 from __future__ import annotations
 
@@ -24,11 +26,25 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _kernel_requested(use_kernel: bool | None, interpret: bool) -> bool:
+    """Whether to run the Pallas kernel (compiled, or interpreted when
+    ``interpret``); raises for a compiled kernel off the TPU."""
+    if interpret:
+        return True
+    if use_kernel is None:
+        return on_tpu()
+    if use_kernel and not on_tpu():
+        raise ValueError(
+            "a compiled Pallas kernel needs a TPU (default backend is "
+            f"{jax.default_backend()!r}); pass interpret=True to run it "
+            "through the Pallas interpreter")
+    return use_kernel
+
+
 # ------------------------------------------------------------------ minplus
 def minplus(a, b, *, use_kernel: bool | None = None, interpret: bool = False):
-    use_kernel = on_tpu() if use_kernel is None else use_kernel
-    if use_kernel or interpret:
-        return _mp.minplus(a, b, interpret=interpret or not on_tpu())
+    if _kernel_requested(use_kernel, interpret):
+        return _mp.minplus(a, b, interpret=interpret)
     return _ref.minplus_ref(a, b)
 
 
@@ -42,12 +58,9 @@ def apsp(cost, n_iters: int, **kw):
 # ---------------------------------------------------------------- link util
 def walk_accumulate(nh, f, delay, *, max_hops: int,
                     use_kernel: bool | None = None, interpret: bool = False):
-    use_kernel = on_tpu() if use_kernel is None else use_kernel
-    if use_kernel or interpret:
-        return _lu.walk_accumulate(
-            nh, f, delay, max_hops=max_hops,
-            interpret=interpret or not on_tpu(),
-        )
+    if _kernel_requested(use_kernel, interpret):
+        return _lu.walk_accumulate(nh, f, delay, max_hops=max_hops,
+                                   interpret=interpret)
     return _ref.walk_accumulate_ref(nh, f, delay, max_hops=max_hops)
 
 

@@ -17,9 +17,10 @@ padding row can never win. ``n_real`` rides in as a (1, 1) array rather
 than a static — the real neighborhood size varies per step and must not
 key the jit cache (the padded shape does).
 
-This is the ``meta_backend="fused-pallas"`` implementation; TPU-only, with
-``interpret=True`` running it on CPU for conformance tests, and the same
-fall-back-to-jnp-on-device-failure contract as kernels/forest.
+This is the ``meta_backend="fused-pallas"`` implementation. Like
+kernels/forest it uses gathers the TPU compiler refuses, so it runs only
+through the Pallas interpreter (``interpret=True``, conformance tests) and
+no default path selects it.
 """
 
 from __future__ import annotations

@@ -58,7 +58,8 @@ def _problem_args(ap: argparse.ArgumentParser) -> None:
                     help="routing backend auto|jnp|pallas (default auto)")
     ap.add_argument("--forest-backend", default="auto",
                     help="surrogate inference backend auto|numpy|jnp|pallas "
-                         "(default auto; pallas falls back to jnp off-TPU)")
+                         "(default auto; pallas runs only in the Pallas "
+                         "interpreter and is refused here)")
 
 
 def _budget_args(ap: argparse.ArgumentParser) -> None:
@@ -314,4 +315,7 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -214,6 +214,17 @@ def test_deadline_guard_is_inert_without_overrun():
 # ---------------------------------------------------------------------------
 # execute_shards: process executor — real aborts, preemptive deadlines
 # ---------------------------------------------------------------------------
+def test_process_pool_refused_on_tpu_host(monkeypatch):
+    """A TPU belongs to one process: on a TPU host the process executor
+    refuses to spawn children instead of letting them contend for the chip
+    or quietly evaluate on the CPU."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="in-process executor"):
+        ShardPool(2)
+
+
 @pytest.mark.slow
 def test_process_abort_breaks_pool_then_rebuild_and_retry_succeeds():
     inj = FaultInjector(faults=(

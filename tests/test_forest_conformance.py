@@ -142,53 +142,40 @@ def test_constant_labels_degenerate_fit():
     np.testing.assert_allclose(out, np.full(9, 3.25), rtol=0, atol=1e-6)
 
 
-# ------------------------------------------------------- fallback contract
-def test_pallas_resolves_off_tpu_with_one_time_warning(monkeypatch):
-    """On a host without a TPU an explicit "pallas" (no interpret) must
-    resolve to "jnp" — never fail inside jit — and warn exactly once
-    (same contract as core.routing's backend resolution)."""
-    import jax
-
-    if jax.default_backend() == "tpu":  # pragma: no cover - CPU container
-        pytest.skip("fallback only exists off-TPU")
-    monkeypatch.setattr(forest_mod, "_PALLAS_FALLBACK_WARNED", False)
-    with pytest.warns(UserWarning, match="falling back to 'jnp'"):
-        assert resolve_forest_backend("pallas") == "jnp"
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # a second warning would raise
-        assert resolve_forest_backend("pallas") == "jnp"
-    # interpret mode runs the kernel anywhere — no fallback, no warning.
-    monkeypatch.setattr(forest_mod, "_PALLAS_FALLBACK_WARNED", False)
+# --------------------------------------------------------- raise contract
+def test_pallas_resolves_off_tpu_with_one_time_warning():
+    """An explicit "pallas" without interpret raises on every platform (the
+    TPU compiler refuses the kernel's gathers), "auto" never resolves to
+    it, and interpret mode resolves to the kernel without a warning."""
+    with pytest.raises(ValueError, match="interpret=True"):
+        resolve_forest_backend("pallas")
+    for batch in (None, 8, 4096):
+        assert resolve_forest_backend("auto", batch=batch) in ("numpy", "jnp")
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         assert resolve_forest_backend("pallas", interpret=True) == "pallas"
 
 
-def test_pallas_forest_predict_falls_back_off_tpu(monkeypatch):
-    """predict(backend="pallas") without interpret goes through the
-    fallback and returns exactly the jnp twin's output."""
-    import jax
-
-    if jax.default_backend() == "tpu":  # pragma: no cover - CPU container
-        pytest.skip("fallback only exists off-TPU")
-    monkeypatch.setattr(forest_mod, "_PALLAS_FALLBACK_WARNED", False)
+def test_pallas_forest_predict_falls_back_off_tpu():
+    """predict(backend="pallas") without interpret raises instead of
+    serving another backend; with interpret it returns the kernel's output,
+    which agrees with the jnp twin."""
     model, rng = _fit(n=120, f=4, n_trees=6)
     xq = rng.uniform(-1, 1, size=(21, 4))
-    with pytest.warns(UserWarning, match="falling back to 'jnp'"):
-        out = model.predict(xq, backend="pallas")
-    np.testing.assert_array_equal(out, model.predict(xq, backend="jnp"))
+    with pytest.raises(ValueError, match="interpret=True"):
+        model.predict(xq, backend="pallas")
+    np.testing.assert_allclose(model.predict(xq, backend="pallas",
+                                             interpret=True),
+                               model.predict(xq, backend="jnp"),
+                               rtol=0, atol=1e-6)
 
 
 def test_on_device_kernel_failure_disables_pallas(monkeypatch):
-    """If the kernel itself fails on real hardware (e.g. Mosaic rejects a
-    lowering), the predict falls back to the jnp twin, warns once, and the
-    process-wide resolution stops picking pallas — "auto" on TPU must never
-    crash an optimizer run mid-search. interpret failures still raise (they
-    are test bugs, not platform limitations)."""
+    """A kernel failure propagates to the caller: nothing catches it,
+    substitutes the jnp twin, or disables the kernel for the process."""
     from repro.kernels import forest as kforest
 
-    monkeypatch.setattr(forest_mod, "_PALLAS_DISABLED", False)
-    monkeypatch.setattr(forest_mod, "_PALLAS_FALLBACK_WARNED", False)
+    assert not hasattr(forest_mod, "_PALLAS_DISABLED")
     model, rng = _fit(n=80, f=4, n_trees=5)
     xq = rng.uniform(-1, 1, size=(9, 4))
     want = model.predict(xq, backend="jnp")
@@ -197,19 +184,11 @@ def test_on_device_kernel_failure_disables_pallas(monkeypatch):
         raise RuntimeError("Mosaic lowering failed")
 
     monkeypatch.setattr(kforest, "forest_predict", boom)
-    with pytest.warns(UserWarning, match="disabling"):
-        out = model._predict_pallas(model._normalize(xq), interpret=False)
-    np.testing.assert_array_equal(out, want)
-    assert forest_mod._PALLAS_DISABLED
-    # Resolution now routes pallas to jnp silently, without re-warning.
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert resolve_forest_backend("pallas") == "jnp"
-    # interpret mode keeps raising — and stays resolvable for tests.
+    for _ in range(2):  # the second call reaches the kernel again
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            model.predict(xq, backend="pallas", interpret=True)
     assert resolve_forest_backend("pallas", interpret=True) == "pallas"
-    with pytest.raises(RuntimeError, match="Mosaic"):
-        model._predict_pallas(model._normalize(xq), interpret=True)
-    monkeypatch.setattr(forest_mod, "_PALLAS_DISABLED", False)
+    np.testing.assert_array_equal(model.predict(xq, backend="jnp"), want)
 
 
 # -------------------------------------------------------------- properties

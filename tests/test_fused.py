@@ -237,14 +237,15 @@ def test_meta_greedy_pallas_matches_fused():
 
 
 def test_pallas_off_tpu_falls_back_to_jnp_tail():
-    """Explicit fused-pallas without interpret on a TPU-less host resolves
-    to the jnp tail at construction (forest backend fallback contract)."""
-    import jax
-
+    """Explicit fused-pallas without interpret raises at construction on
+    every platform; no scorer silently serves the jnp tail instead."""
     spec = spec_tiny()
-    sc = MetaScorer(spec, _fit_forest(spec), backend="fused-pallas")
-    on_tpu = jax.default_backend() == "tpu"
-    assert sc.pallas == on_tpu
+    model = _fit_forest(spec)
+    with pytest.raises(ValueError, match="interpret=True"):
+        MetaScorer(spec, model, backend="fused-pallas")
+    assert MetaScorer(spec, model, backend="fused-pallas",
+                      interpret=True).pallas
+    assert not MetaScorer(spec, model, backend="fused").pallas
 
 
 # ------------------------------------------------------------ PHV jnp twin
@@ -291,18 +292,21 @@ def test_phv_context_backend_knob():
 
 
 # ------------------------------------------------------------- spmd parity
-def test_spmd_evaluator_matches_serial():
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_spmd_evaluator_matches_serial(backend):
     """Evaluator built under spmd_scope (1 device here) is bit-equal to the
-    plain path — sharding the batch axis reorders no reductions."""
+    plain path — sharding the batch axis reorders no reductions. The
+    pallas case runs the APSP kernel (interpreted) inside shard_map."""
     from repro.core.evaluate import make_spmd_mesh, spmd_scope
 
     spec = spec_tiny()
     f = traffic_matrix(spec, "BFS")
     rng = np.random.default_rng(5)
     designs = [random_design(spec, rng) for _ in range(6)]
+    interp = backend == "pallas"
     ev = Evaluator(spec, f)
     with spmd_scope(make_spmd_mesh()):
-        ev_s = Evaluator(spec, f)
+        ev_s = Evaluator(spec, f, backend=backend, interpret=interp)
     assert ev_s._spmd_fn is not None and ev._spmd_fn is None
     a, aux_a = ev.batch_aux(designs)
     b, aux_b = ev_s.batch_aux(designs)
@@ -333,6 +337,9 @@ with spmd_scope(make_spmd_mesh()):
     ev = Evaluator(spec, f)
 got = ev.batch(designs)  # pads 6 -> 8, divisible by 4 devices
 np.testing.assert_array_equal(want, got)
+with spmd_scope(make_spmd_mesh()):
+    ev_k = Evaluator(spec, f, backend="pallas", interpret=True)
+np.testing.assert_array_equal(want, ev_k.batch(designs))
 print("SPMD-OK")
 """
     env = dict(os.environ,
