@@ -11,6 +11,10 @@ Phases (one chip), through the public API:
      Its APSP distances equal the host oracle ``routing.apsp_np`` and its
      objective rows equal ``Evaluator(backend="jnp")`` on the same chip,
      exactly (every finite path cost is a small integer, exact in f32).
+     The compiled path-walk kernel (``kernels.ops.walk_accumulate``, the
+     link report's walk) on one of those designs equals the host oracle
+     ``walk_accumulate_np``: exactly under integer traffic, and within
+     rtol 1e-4 under the phase's real traffic (summation order differs).
   b. Paper deployment: registry ``stage_batch`` on ``spec_64`` with the
      paper's AVG traffic at a fixed budget; the RunResult round-trips
      through JSON and the evaluation count stays within budget.
@@ -40,6 +44,9 @@ import os
 import sys
 import time
 import traceback
+
+# No TPU library log files under /tmp (set before JAX is imported).
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -155,10 +162,49 @@ def phase_evaluator(rec: dict) -> None:
         objs_j = ev_j.batch(designs)
         check(np.array_equal(objs_k, objs_j),
               f"{name}: pallas and jnp objective rows differ")
-        n_finite = int(np.isfinite(objs_k).all(axis=1).sum())
+        finite = np.isfinite(objs_k).all(axis=1)
+        n_finite = int(finite.sum())
         check(n_finite > 0, f"{name}: no design evaluated finite")
+        i = int(np.argmax(finite))
+        walk = _check_walk(name, ev_k.consts, designs[i], costs[i], dist_h[i],
+                           f)
         rec["specs"][name] = {"designs": len(designs), "finite": n_finite,
-                              "evals": ev_k.n_evals, "calls": ev_k.n_calls}
+                              "evals": ev_k.n_evals, "calls": ev_k.n_calls,
+                              "walk": walk}
+
+
+def _check_walk(name, consts, design, cost, dist, f) -> dict:
+    """The compiled path-walk kernel against the scalar-loop host oracle."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import routing
+    from repro.kernels import ops
+    from repro.kernels.ref import walk_accumulate_np
+
+    n = len(design.perm)
+    nh = routing.next_hop_np(cost, dist)
+    off = 1.0 - np.eye(n, dtype=np.float32)
+    f_int = (np.random.default_rng(SEED).integers(0, 16, (n, n))
+             * off).astype(np.float32)
+    perm = np.asarray(design.perm)
+    f_real = (np.asarray(f)[perm][:, perm] * off).astype(np.float32)
+    delay = np.asarray(consts.link_delay)
+    out = {}
+    for label, fs, exact in (("int", f_int, True), ("real", f_real, False)):
+        got = [np.asarray(x) for x in ops.walk_accumulate(
+            jnp.asarray(nh), jnp.asarray(fs), jnp.asarray(delay),
+            max_hops=consts.max_hops, use_kernel=True, interpret=INTERPRET)]
+        want = walk_accumulate_np(nh, fs, delay, max_hops=consts.max_hops)
+        for field, g, w in zip(("hops", "dsum", "util", "visits"), got, want):
+            ok = (np.array_equal(g, w) if exact else
+                  np.allclose(g, w, rtol=1e-4, atol=1e-6 * float(w.max())))
+            check(ok, f"{name}: walk kernel {field} differs from "
+                      f"walk_accumulate_np under {label} traffic (max abs "
+                      f"diff {float(np.abs(g - w).max())})")
+        out[label] = {"max_abs_diff": max(float(np.abs(g - w).max())
+                                          for g, w in zip(got, want))}
+    return out
 
 
 def _stage_batch(rec: dict, problem, budget, cfg: dict) -> None:

@@ -34,6 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 INF = 1.0e9
+#: block edge above 128 tiles; a multiple of 128, the lane width
+_BLOCK = 128
 
 
 def _minplus_kernel(a_ref, b_ref, o_ref):
@@ -47,19 +49,17 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
     o_ref[0] = jnp.minimum(o_ref[0], cand)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def minplus(
     a: jax.Array,  # (B, N, N)
     b: jax.Array,  # (B, N, N)
     *,
-    block: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """out[b, i, j] = min_k a[b, i, k] + b[b, k, j]. Pads N with +INF rows
-    (neutral for min-plus) up to a multiple of ``block`` when N > ``block``;
-    ``block`` must be a multiple of 128 (the lane width)."""
+    (neutral for min-plus) up to a multiple of ``_BLOCK`` when N > ``_BLOCK``."""
     bsz, n, _ = a.shape
-    bm = bn = bk = min(block, n)
+    bm = bn = bk = min(_BLOCK, n)
     npad = -(-n // bk) * bk
     if npad != n:
         pad = ((0, 0), (0, npad - n), (0, npad - n))

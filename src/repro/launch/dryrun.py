@@ -25,6 +25,7 @@ are bugs in the framework. Usage:
 
     python -m repro.launch.dryrun --arch yi-6b --shape train_4k
     python -m repro.launch.dryrun --all [--multi-pod-only|--single-pod-only]
+    python -m repro.launch.dryrun ... --out DIR   # records elsewhere
 """
 
 import argparse
@@ -97,14 +98,14 @@ def _memory_dict(compiled) -> dict:
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
              policy: shd.Policy | None = None,
              cfg_overrides: dict | None = None,
-             save: bool = True) -> dict:
+             save: bool = True, out_dir: str = OUT_DIR) -> dict:
     policy = policy or shd.default_policy_for(SHAPES[shape_name].kind)
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
            "status": cell_status(arch, shape_name)}
     if not applicable(arch, shape_name):
         if save:
-            _save(rec)
+            _save(rec, out_dir)
         return rec
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
@@ -136,14 +137,14 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         rec.update(status="FAIL", error=f"{type(e).__name__}: {e}",
                    traceback=traceback.format_exc()[-2000:])
     if save:
-        _save(rec)
+        _save(rec, out_dir)
     return rec
 
 
-def _save(rec: dict):
-    os.makedirs(OUT_DIR, exist_ok=True)
+def _save(rec: dict, out_dir: str):
+    os.makedirs(out_dir, exist_ok=True)
     name = f"{rec['arch']}__{rec['shape']}__{rec['mesh']}.json"
-    with open(os.path.join(OUT_DIR, name), "w") as fh:
+    with open(os.path.join(out_dir, name), "w") as fh:
         json.dump(rec, fh, indent=1, default=str)
 
 
@@ -154,6 +155,8 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--multi-pod-only", action="store_true")
     ap.add_argument("--single-pod-only", action="store_true")
+    ap.add_argument("--out", default=OUT_DIR,
+                    help="directory for the per-cell JSON records")
     args = ap.parse_args()
 
     meshes = [False, True]
@@ -172,7 +175,7 @@ def main():
     n_fail = 0
     for arch, shape_name in cells:
         for mp in meshes:
-            rec = run_cell(arch, shape_name, multi_pod=mp)
+            rec = run_cell(arch, shape_name, multi_pod=mp, out_dir=args.out)
             tag = "MULTI " if mp else "single"
             if rec["status"] == "ok":
                 mem = rec["memory"].get("temp_size_in_bytes", 0) + \

@@ -48,16 +48,16 @@ def test_dryrun_cell_subprocess(tmp_path):
     env["PYTHONPATH"] = "src"
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
-         "--arch", "whisper-base", "--shape", "decode_32k"],
+         "--arch", "whisper-base", "--shape", "decode_32k",
+         "--out", str(tmp_path)],
         capture_output=True, text=True, env=env, timeout=600,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "OK" in proc.stdout
-    # Artifacts recorded for both meshes.
-    base = os.path.join("experiments", "dryrun")
+    # Artifacts recorded for both meshes, outside the checkout's records.
     for mesh in ("pod16x16", "pod2x16x16"):
-        path = os.path.join(base, f"whisper-base__decode_32k__{mesh}.json")
+        path = os.path.join(tmp_path, f"whisper-base__decode_32k__{mesh}.json")
         assert os.path.exists(path)
         rec = json.load(open(path))
         assert rec["status"] == "ok"

@@ -8,9 +8,11 @@ kernel is in the compiled program (``tpu_custom_call``).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
-this file.
+this file. The library's own log files are turned off (``TPU_LOG_DIR``),
+so a run writes nothing outside its checkout.
 """
 
+import os
 from functools import partial
 
 import jax
@@ -23,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 def topo():
     from jax.experimental import topologies
 
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -40,7 +43,7 @@ def _compile(fn, *shapes):
     return compiled.as_text()
 
 
-@pytest.mark.parametrize("n", [64, 256])
+@pytest.mark.parametrize("n", [36, 64, 256])
 def test_minplus_compiles_for_v5e(one_chip, n):
     from repro.kernels.minplus import minplus
 
@@ -48,10 +51,10 @@ def test_minplus_compiles_for_v5e(one_chip, n):
     assert "tpu_custom_call" in _compile(lambda a: minplus(a, a), x)
 
 
-def test_walk_accumulate_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("n", [64, 256])
+def test_walk_accumulate_compiles_for_v5e(one_chip, n):
     from repro.kernels.link_util import walk_accumulate
 
-    n = 64
     s = partial(jax.ShapeDtypeStruct, sharding=one_chip)
     text = _compile(
         lambda nh, f, dl: walk_accumulate(nh, f, dl, max_hops=24),
@@ -59,11 +62,29 @@ def test_walk_accumulate_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("spec_name", ["spec_64", "spec_large"])
+def test_single_device_apsp_compiles_for_v5e(one_chip, spec_name):
+    """The batched APSP as ``Evaluator.batch_aux`` dispatches it on one
+    chip (its own jit, ``backend="pallas"``, the spec's squaring count)."""
+    from repro.core import problem, routing
+    from repro.core.objectives import make_consts
+
+    spec = getattr(problem, spec_name)()
+    n, b = spec.n_tiles, 64
+    iters = make_consts(spec).apsp_iters
+    x = jax.ShapeDtypeStruct((b, n, n), jnp.float32, sharding=one_chip)
+    text = _compile(
+        lambda c: routing.apsp_batched(c, iters, backend="pallas"), x)
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("backend", ["pallas", "jnp"])
 def test_evaluator_step_compiles_for_v5e(one_chip, backend):
-    """The Evaluator's dispatch body at spec_64 (cost build → batched APSP
-    → objective walk); the Pallas APSP kernel is in it exactly when the
-    routing backend is ``pallas``, which ``auto`` resolves to on a TPU."""
+    """The spmd executor's per-shard body at spec_64 (cost build → batched
+    APSP → objective walk in one program); the Pallas APSP kernel is in it
+    exactly when the routing backend is ``pallas``, which ``auto`` resolves
+    to on a TPU. The single-device path dispatches the same stages as
+    separate jits (its APSP is compiled above)."""
     from repro.core import spec_64
     from repro.core.evaluate import batch_pipeline
     from repro.core.objectives import make_consts
