@@ -68,43 +68,30 @@ def check(cond, msg: str) -> None:
         raise CheckFailed(msg)
 
 
-class CompileMonitor:
-    """Compile seconds and persistent-cache hits/misses, from JAX's own
-    monitoring events, attributed to the phase that is running."""
+@contextlib.contextmanager
+def phase(rec: dict):
+    """Time one phase; its compile seconds and persistent-cache hits and
+    misses are deltas of the program's own compile spans and counters
+    (``repro.telemetry``)."""
+    from repro import telemetry
 
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
+    def read():
+        t = telemetry.totals()
+        return (sum(t.get(k, {}).get("seconds", 0.0)
+                    for k in ("jit.compile", "jit.lower")),
+                t.get("jit.cache_hit", {}).get("count", 0),
+                t.get("jit.cache_miss", {}).get("count", 0))
 
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.cur: dict | None = None
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if self.cur is not None and event in self._DURATIONS:
-            self.cur["compile_s"] += duration
-
-    def _on_event(self, event, **_):
-        if self.cur is None:
-            return
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cur["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cur["cache_misses"] += 1
-
-    @contextlib.contextmanager
-    def phase(self, rec: dict):
-        rec.update(compile_s=0.0, cache_hits=0, cache_misses=0)
-        self.cur = rec
-        t0 = time.perf_counter()
-        try:
-            yield rec
-        finally:
-            rec["wall_s"] = time.perf_counter() - t0
-            self.cur = None
+    before = read()
+    t0 = time.perf_counter()
+    try:
+        yield rec
+    finally:
+        rec["wall_s"] = time.perf_counter() - t0
+        after = read()
+        rec.update(compile_s=after[0] - before[0],
+                   cache_hits=after[1] - before[1],
+                   cache_misses=after[2] - before[2])
 
 
 def expect_backends(rec: dict, **got) -> None:
@@ -370,11 +357,10 @@ def main(argv=None) -> int:
     phases = ([("dist4", phase_dist4)] if args.chips == 4 else
               [("a_evaluator", phase_evaluator), ("b_paper", phase_paper),
                ("c_system", phase_system), ("d_service", phase_service)])
-    monitor = CompileMonitor()
     failed = []
     for name, fn in phases:
         rec = {"phase": name}
-        with monitor.phase(rec):
+        with phase(rec):
             try:
                 fn(rec)
                 rec["ok"] = True

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from . import routing
 from .objectives import (N_OBJ, SpecConsts, design_cost, design_cost_np,
                          evaluate_with_tables, make_consts)
@@ -183,21 +184,27 @@ class Evaluator:
             ndev = self.mesh.devices.size
             if pad % ndev:
                 pad = -(-pad // ndev) * ndev
-        perms = np.stack([d.perm for d in designs] + [designs[-1].perm] * (pad - b))
-        adjs = np.stack([d.adj for d in designs] + [designs[-1].adj] * (pad - b))
-        perms_j, adjs_j = jnp.asarray(perms), jnp.asarray(adjs)
-        if self._spmd_fn is not None:
-            objs, aux = self._spmd_fn(perms_j, adjs_j, self.f)
-        else:
-            costs = self._cost_fn(adjs_j)
-            dist, nh = routing.routing_tables_batched(
-                costs, self.consts.apsp_iters,
-                backend=self.backend, interpret=self.interpret)
-            objs, aux = self._eval_fn(perms_j, adjs_j, self.f, dist, nh)
-        self.n_evals += b
-        self.n_calls += 1
-        aux = {k: np.asarray(v[:b]) for k, v in aux.items()}
-        return np.asarray(objs[:b], dtype=np.float64), aux
+        with telemetry.span("eval.dispatch", rows=b, padded=pad):
+            with telemetry.span("eval.pack"):
+                perms = np.stack([d.perm for d in designs]
+                                 + [designs[-1].perm] * (pad - b))
+                adjs = np.stack([d.adj for d in designs]
+                                + [designs[-1].adj] * (pad - b))
+                perms_j, adjs_j = jnp.asarray(perms), jnp.asarray(adjs)
+            if self._spmd_fn is not None:
+                objs, aux = self._spmd_fn(perms_j, adjs_j, self.f)
+            else:
+                costs = self._cost_fn(adjs_j)
+                dist, nh = routing.routing_tables_batched(
+                    costs, self.consts.apsp_iters,
+                    backend=self.backend, interpret=self.interpret)
+                objs, aux = self._eval_fn(perms_j, adjs_j, self.f, dist, nh)
+            self.n_evals += b
+            self.n_calls += 1
+            with telemetry.span("eval.wait"):
+                aux = {k: np.asarray(v[:b]) for k, v in aux.items()}
+                objs = np.asarray(objs[:b], dtype=np.float64)
+        return objs, aux
 
     # -------------------------------------------------------------- moves
     def batch_moves(self, moves) -> np.ndarray:
@@ -219,28 +226,30 @@ class Evaluator:
         if not self.delta_on:
             return self.batch([d for m in mvs for d in m.materialize_all()])
         perms, adjs, dists, nhs = [], [], [], []
-        for mv in mvs:
-            t0 = self._host_tables(mv.base)
-            for s in range(mv.swaps.shape[0]):
-                a, b = int(mv.swaps[s, 0]), int(mv.swaps[s, 1])
-                p = mv.base.perm.copy()
-                p[a], p[b] = p[b], p[a]
-                perms.append(p)
-                adjs.append(mv.base.adj)
-                dists.append(t0.dist)
-                nhs.append(t0.nh)
-                self.delta_stats["swap"] += 1
-            for k in range(mv.rem.shape[0]):
-                rem = (int(mv.rem[k, 0]), int(mv.rem[k, 1]))
-                add = (int(mv.add[k, 0]), int(mv.add[k, 1]))
-                t = self._moved_tables(t0, rem, add)
-                adj2 = mv.base.adj.copy()
-                adj2[rem[0], rem[1]] = adj2[rem[1], rem[0]] = False
-                adj2[add[0], add[1]] = adj2[add[1], add[0]] = True
-                perms.append(mv.base.perm)
-                adjs.append(adj2)
-                dists.append(t.dist)
-                nhs.append(t.nh)
+        with telemetry.span("eval.tables",
+                            moves=sum(len(mv) for mv in mvs)):
+            for mv in mvs:
+                t0 = self._host_tables(mv.base)
+                for s in range(mv.swaps.shape[0]):
+                    a, b = int(mv.swaps[s, 0]), int(mv.swaps[s, 1])
+                    p = mv.base.perm.copy()
+                    p[a], p[b] = p[b], p[a]
+                    perms.append(p)
+                    adjs.append(mv.base.adj)
+                    dists.append(t0.dist)
+                    nhs.append(t0.nh)
+                    self.delta_stats["swap"] += 1
+                for k in range(mv.rem.shape[0]):
+                    rem = (int(mv.rem[k, 0]), int(mv.rem[k, 1]))
+                    add = (int(mv.add[k, 0]), int(mv.add[k, 1]))
+                    t = self._moved_tables(t0, rem, add)
+                    adj2 = mv.base.adj.copy()
+                    adj2[rem[0], rem[1]] = adj2[rem[1], rem[0]] = False
+                    adj2[add[0], add[1]] = adj2[add[1], add[0]] = True
+                    perms.append(mv.base.perm)
+                    adjs.append(adj2)
+                    dists.append(t.dist)
+                    nhs.append(t.nh)
         return self._eval_from_tables(perms, adjs, dists, nhs)
 
     def note_accept(self, mv: NeighborMoves, j: int) -> None:
@@ -256,15 +265,16 @@ class Evaluator:
         k = j - s
         rem = (int(mv.rem[k, 0]), int(mv.rem[k, 1]))
         add = (int(mv.add[k, 0]), int(mv.add[k, 1]))
-        adj2 = mv.base.adj.copy()
-        adj2[rem[0], rem[1]] = adj2[rem[1], rem[0]] = False
-        adj2[add[0], add[1]] = adj2[add[1], add[0]] = True
-        key = np.packbits(adj2).tobytes()
-        if key in self._tab_cache:
-            self._tab_cache.move_to_end(key)
-            return
-        t = self._moved_tables(self._host_tables(mv.base), rem, add)
-        self._tab_put(key, t)
+        with telemetry.span("eval.tables", moves=1):
+            adj2 = mv.base.adj.copy()
+            adj2[rem[0], rem[1]] = adj2[rem[1], rem[0]] = False
+            adj2[add[0], add[1]] = adj2[add[1], add[0]] = True
+            key = np.packbits(adj2).tobytes()
+            if key in self._tab_cache:
+                self._tab_cache.move_to_end(key)
+                return
+            t = self._moved_tables(self._host_tables(mv.base), rem, add)
+            self._tab_put(key, t)
 
     def _host_tables(self, base: Design) -> routing.HostTables:
         key = np.packbits(base.adj).tobytes()
@@ -316,14 +326,21 @@ class Evaluator:
             pad = 1 << max(0, (b - 1).bit_length())
             sl = slice(i, i + b)
             tail = pad - b
-            pj = jnp.asarray(np.stack(perms[sl] + [perms[i + b - 1]] * tail))
-            aj = jnp.asarray(np.stack(adjs[sl] + [adjs[i + b - 1]] * tail))
-            dj = jnp.asarray(np.stack(dists[sl] + [dists[i + b - 1]] * tail))
-            nj = jnp.asarray(np.stack(nhs[sl] + [nhs[i + b - 1]] * tail))
-            objs, _ = self._eval_fn(pj, aj, self.f, dj, nj)
-            self.n_evals += b
-            self.n_calls += 1
-            out.append(np.asarray(objs[:b], dtype=np.float64))
+            with telemetry.span("eval.dispatch", rows=b, padded=pad):
+                with telemetry.span("eval.pack"):
+                    pj = jnp.asarray(np.stack(
+                        perms[sl] + [perms[i + b - 1]] * tail))
+                    aj = jnp.asarray(np.stack(
+                        adjs[sl] + [adjs[i + b - 1]] * tail))
+                    dj = jnp.asarray(np.stack(
+                        dists[sl] + [dists[i + b - 1]] * tail))
+                    nj = jnp.asarray(np.stack(
+                        nhs[sl] + [nhs[i + b - 1]] * tail))
+                objs, _ = self._eval_fn(pj, aj, self.f, dj, nj)
+                self.n_evals += b
+                self.n_calls += 1
+                with telemetry.span("eval.wait"):
+                    out.append(np.asarray(objs[:b], dtype=np.float64))
         return np.concatenate(out, axis=0)
 
     # ---------------------------------------------------------------- EDP

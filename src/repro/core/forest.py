@@ -218,11 +218,12 @@ def _predict_flat_jnp_fn():
     import jax
 
     @partial(jax.jit, static_argnames=("depth", "n_trees", "n_nodes"))
-    def run(thrfeat, child, value, xn, depth, n_trees, n_nodes):
+    def forest_traverse(thrfeat, child, value, xn, depth, n_trees,
+                        n_nodes):
         return flat_forest_eval(thrfeat, child, value, xn,
                                 depth, n_trees, n_nodes)
 
-    return run
+    return forest_traverse
 
 
 _JITTED_FLAT = None

@@ -235,16 +235,16 @@ def _score_moves_fn():
     from .forest import flat_forest_eval
 
     @partial(jax.jit, static_argnames=("depth", "n_trees", "n_nodes"))
-    def run(c, thrfeat, child, value, xm, xs,
-            base_perm, base_lm, base_scalars, sa, sb, er, ea,
-            *, depth, n_trees, n_nodes):
+    def meta_score_moves(c, thrfeat, child, value, xm, xs,
+                         base_perm, base_lm, base_scalars, sa, sb, er, ea,
+                         *, depth, n_trees, n_nodes):
         feats = _fused_features(c, base_perm, base_lm, base_scalars,
                                 sa, sb, er, ea)
         xn = (feats - xm) / xs
         return flat_forest_eval(thrfeat, child, value, xn,
                                 depth, n_trees, n_nodes)
 
-    return run
+    return meta_score_moves
 
 
 class MetaScorer:

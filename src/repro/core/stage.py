@@ -19,6 +19,7 @@ import dataclasses
 
 import numpy as np
 
+from .. import telemetry
 from .evaluate import Evaluator
 from .features import design_features_batch
 from .forest import RegressionForest
@@ -38,6 +39,12 @@ def _merge_forest_kwargs(forest_kwargs: dict | None,
     if forest_backend is not None:
         fk.setdefault("backend", forest_backend)
     return fk
+
+
+def _features(spec: SystemSpec, designs: list[Design]) -> np.ndarray:
+    """Surrogate features of trajectories and restart designs."""
+    with telemetry.span("stage.features", rows=len(designs)):
+        return design_features_batch(spec, designs)
 
 
 @dataclasses.dataclass
@@ -91,10 +98,13 @@ def _meta_greedy_host(
     d_curr = d_from
     v_curr = float(model.predict(design_features_batch(spec, [d_curr]))[0])
     for _ in range(max_steps):
-        cands = sample_neighbors(spec, d_curr, rng, n_swaps, n_link_moves)
-        if not cands:
-            break
-        vals = model.predict(design_features_batch(spec, cands))
+        with telemetry.span("meta.step") as sp:
+            cands = sample_neighbors(spec, d_curr, rng, n_swaps,
+                                     n_link_moves)
+            sp.attrs["cands"] = len(cands)
+            if not cands:
+                break
+            vals = model.predict(design_features_batch(spec, cands))
         j = int(np.argmax(vals))
         if vals[j] <= v_curr + 1e-12:
             break
@@ -142,11 +152,13 @@ def _meta_greedy(
     d_curr = d_from
     v_curr = sc.score_base(d_curr)
     for _ in range(max_steps):
-        moves = sample_neighbor_moves(spec, d_curr, rng, n_swaps,
-                                      n_link_moves)
-        if not len(moves):
-            break
-        j, vj = sc.score_moves(moves)
+        with telemetry.span("meta.step") as sp:
+            moves = sample_neighbor_moves(spec, d_curr, rng, n_swaps,
+                                          n_link_moves)
+            sp.attrs["cands"] = len(moves)
+            if not len(moves):
+                break
+            j, vj = sc.score_moves(moves)
         if vj <= v_curr + 1e-12:
             break
         d_curr, v_curr = moves.materialize(j), vj
@@ -192,51 +204,55 @@ def moo_stage(
     for it in range(iters_max):
         if max_evals is not None and ev.n_evals >= max_evals:
             break
-        predicted = (
-            float(model.predict(design_features_batch(spec, [d_start]))[0])
-            if model is not None
-            else None
-        )
-        res: LocalResult = local_search(
-            spec, ev, ctx, d_start, rng,
-            n_swaps=n_swaps, n_link_moves=n_link_moves,
-            max_steps=max_local_steps, history=history, max_evals=max_evals,
-        )
-        n_local += 1
-        if predicted is not None and res.phv > 0:
-            eval_errors.append((it, abs(predicted - res.phv) / res.phv))
+        with telemetry.span("stage.iter"):
+            predicted = (
+                float(model.predict(_features(spec, [d_start]))[0])
+                if model is not None
+                else None
+            )
+            res: LocalResult = local_search(
+                spec, ev, ctx, d_start, rng,
+                n_swaps=n_swaps, n_link_moves=n_link_moves,
+                max_steps=max_local_steps, history=history,
+                max_evals=max_evals,
+            )
+            n_local += 1
+            if predicted is not None and res.phv > 0:
+                eval_errors.append((it, abs(predicted - res.phv) / res.phv))
 
-        # Merge local set into global set (Alg. 2 lines 3-4).
-        merged = s_global.merged_with(
-            res.local.designs, res.local.objs, ctx.obj_idx
-        )
-        new_keys = merged.keys() - s_global.keys()
-        local_keys = res.local.keys()
-        s_global = merged
-        if not (new_keys & local_keys):
-            # Local search contributed nothing new — converged (lines 5-6).
-            converged = True
-            break
+            # Merge local set into global set (Alg. 2 lines 3-4).
+            merged = s_global.merged_with(
+                res.local.designs, res.local.objs, ctx.obj_idx
+            )
+            new_keys = merged.keys() - s_global.keys()
+            local_keys = res.local.keys()
+            s_global = merged
+            if not (new_keys & local_keys):
+                # Local search contributed nothing new — converged (5-6).
+                converged = True
+                break
 
-        # Aggregate training examples: every trajectory design is labeled
-        # with the PHV its local search achieved (line 7).
-        x_train.extend(design_features_batch(spec, res.traj))
-        y_train.extend([res.phv] * len(res.traj))
+            # Aggregate training examples: every trajectory design is
+            # labeled with the PHV its local search achieved (line 7).
+            x_train.extend(_features(spec, res.traj))
+            y_train.extend([res.phv] * len(res.traj))
 
-        fk = _merge_forest_kwargs(forest_kwargs, forest_backend)
-        model = RegressionForest(seed=seed + it, **fk).fit(
-            np.stack(x_train), np.asarray(y_train)
-        )
+            fk = _merge_forest_kwargs(forest_kwargs, forest_backend)
+            with telemetry.span("stage.fit", rows=len(x_train)):
+                model = RegressionForest(seed=seed + it, **fk).fit(
+                    np.stack(x_train), np.asarray(y_train)
+                )
 
-        d_restart = _meta_greedy(
-            spec, model, res.d_last, rng,
-            n_swaps=n_swaps, n_link_moves=n_link_moves,
-            backend=meta_backend,
-        )
-        if d_restart.key() == res.d_last.key():
-            d_start = random_design(spec, rng)          # lines 10-11
-        else:
-            d_start = d_restart                          # line 13
+            with telemetry.span("stage.meta"):
+                d_restart = _meta_greedy(
+                    spec, model, res.d_last, rng,
+                    n_swaps=n_swaps, n_link_moves=n_link_moves,
+                    backend=meta_backend,
+                )
+                if d_restart.key() == res.d_last.key():
+                    d_start = random_design(spec, rng)      # lines 10-11
+                else:
+                    d_start = d_restart                      # line 13
 
     return StageResult(
         global_set=s_global,
@@ -353,8 +369,9 @@ def stage_batch(
         if x_init.shape[0]:
             # Warm surrogate: seeded past the per-iteration range (it <
             # iters_max) so the entry fit never collides with a refit seed.
-            model = RegressionForest(seed=seed + iters_max, **fk).fit(
-                x_init, y_init)
+            with telemetry.span("stage.fit", rows=x_init.shape[0]):
+                model = RegressionForest(seed=seed + iters_max, **fk).fit(
+                    x_init, y_init)
     converged = False
     n_local = 0
     next_starts = list(starts)
@@ -362,73 +379,82 @@ def stage_batch(
     for it in range(iters_max):
         if max_evals is not None and ev.n_evals >= max_evals:
             break
-        predicted = (
-            model.predict(design_features_batch(spec, starts))
-            if model is not None
-            else None
-        )
-        results = local_search_batch(
-            spec, ev, ctx, starts, rng,
-            n_swaps=n_swaps, n_link_moves=n_link_moves,
-            max_steps=max_local_steps, history=history, max_evals=max_evals,
-            seed_set=s_global if s_global.designs else None,
-        )
-        n_local += len(results)
-        next_starts = [res.d_last for res in results]
+        with telemetry.span("stage.iter"):
+            predicted = (
+                model.predict(_features(spec, starts))
+                if model is not None
+                else None
+            )
+            results = local_search_batch(
+                spec, ev, ctx, starts, rng,
+                n_swaps=n_swaps, n_link_moves=n_link_moves,
+                max_steps=max_local_steps, history=history,
+                max_evals=max_evals,
+                seed_set=s_global if s_global.designs else None,
+            )
+            n_local += len(results)
+            next_starts = [res.d_last for res in results]
 
-        any_new = False
-        for ci, res in enumerate(results):
-            if predicted is not None and res.phv > 0:
-                eval_errors.append((it, abs(float(predicted[ci]) - res.phv) / res.phv))
-            merged = s_global.merged_with(
-                res.local.designs, res.local.objs, ctx.obj_idx)
-            if merged.keys() - s_global.keys():  # new keys can only be local
-                any_new = True
-            s_global = merged
-            x_train.extend(design_features_batch(spec, res.traj))
-            y_train.extend([res.phv] * len(res.traj))
+            any_new = False
+            for ci, res in enumerate(results):
+                if predicted is not None and res.phv > 0:
+                    eval_errors.append(
+                        (it, abs(float(predicted[ci]) - res.phv) / res.phv))
+                merged = s_global.merged_with(
+                    res.local.designs, res.local.objs, ctx.obj_idx)
+                if merged.keys() - s_global.keys():  # new keys are local
+                    any_new = True
+                s_global = merged
+                x_train.extend(_features(spec, res.traj))
+                y_train.extend([res.phv] * len(res.traj))
 
-        def _refit_and_restart():
-            xs = np.stack(x_train)
-            ys = np.asarray(y_train, dtype=np.float64)
-            if x_init is not None and x_init.shape[0]:
-                xs = np.vstack([x_init, xs])
-                ys = np.concatenate([y_init, ys])
-            m = RegressionForest(seed=seed + it, **fk).fit(xs, ys)
-            # One scorer per refit, shared by every chain's meta search
-            # (device-resident forest tensors transfer once, not K times).
-            sc = (MetaScorer(spec, m, backend=meta_backend)
-                  if meta_backend != "host" else None)
-            new_starts = []
-            for res in results:
-                d_restart = _meta_greedy(
-                    spec, m, res.d_last, rng,
-                    n_swaps=n_swaps, n_link_moves=n_link_moves,
-                    backend=meta_backend, scorer=sc,
-                )
-                if d_restart.key() == res.d_last.key():
-                    new_starts.append(random_design(spec, rng))  # lines 10-11
-                else:
-                    new_starts.append(d_restart)                  # line 13
-            return m, new_starts
+            def _refit_and_restart():
+                with telemetry.span("stage.fit") as sp:
+                    xs = np.stack(x_train)
+                    ys = np.asarray(y_train, dtype=np.float64)
+                    if x_init is not None and x_init.shape[0]:
+                        xs = np.vstack([x_init, xs])
+                        ys = np.concatenate([y_init, ys])
+                    sp.attrs["rows"] = len(xs)
+                    m = RegressionForest(seed=seed + it, **fk).fit(xs, ys)
+                with telemetry.span("stage.meta"):
+                    # One scorer per refit, shared by every chain's meta
+                    # search (device-resident forest tensors transfer
+                    # once, not K times).
+                    sc = (MetaScorer(spec, m, backend=meta_backend)
+                          if meta_backend != "host" else None)
+                    new_starts = []
+                    for res in results:
+                        d_restart = _meta_greedy(
+                            spec, m, res.d_last, rng,
+                            n_swaps=n_swaps, n_link_moves=n_link_moves,
+                            backend=meta_backend, scorer=sc,
+                        )
+                        if d_restart.key() == res.d_last.key():
+                            new_starts.append(
+                                random_design(spec, rng))     # lines 10-11
+                        else:
+                            new_starts.append(d_restart)      # line 13
+                return m, new_starts
 
-        if not any_new:
-            converged = True
-            if checkpoint_restarts:
-                # The meta search costs no objective evaluations — still
-                # pick the restarts a continuing run would use, so a
-                # resuming coordinator round (repro.dist.sync) doesn't
-                # relaunch chains at their already-locally-optimal d_last
-                # and instantly re-converge on budget it could have spent
-                # exploring. Opt-in: callers that never read next_starts
-                # (the registry driver, the benchmarks) skip the refit.
-                _, next_starts = _refit_and_restart()
-            break
-        if max_evals is not None and ev.n_evals >= max_evals:
-            break
+            if not any_new:
+                converged = True
+                if checkpoint_restarts:
+                    # The meta search costs no objective evaluations —
+                    # still pick the restarts a continuing run would use,
+                    # so a resuming coordinator round (repro.dist.sync)
+                    # doesn't relaunch chains at their already-locally-
+                    # optimal d_last and instantly re-converge on budget
+                    # it could have spent exploring. Opt-in: callers that
+                    # never read next_starts (the registry driver, the
+                    # benchmarks) skip the refit.
+                    _, next_starts = _refit_and_restart()
+                break
+            if max_evals is not None and ev.n_evals >= max_evals:
+                break
 
-        model, starts = _refit_and_restart()
-        next_starts = list(starts)
+            model, starts = _refit_and_restart()
+            next_starts = list(starts)
 
     return StageBatchResult(
         global_set=s_global,
