@@ -202,8 +202,10 @@ class Evaluator:
             self.n_evals += b
             self.n_calls += 1
             with telemetry.span("eval.wait"):
-                aux = {k: np.asarray(v[:b]) for k, v in aux.items()}
-                objs = np.asarray(objs[:b], dtype=np.float64)
+                # slice the padded outputs on the host: an eager device
+                # slice would compile once per new row count b.
+                aux = {k: np.asarray(v)[:b] for k, v in aux.items()}
+                objs = np.asarray(objs, dtype=np.float64)[:b]
         return objs, aux
 
     # -------------------------------------------------------------- moves
@@ -340,7 +342,7 @@ class Evaluator:
                 self.n_evals += b
                 self.n_calls += 1
                 with telemetry.span("eval.wait"):
-                    out.append(np.asarray(objs[:b], dtype=np.float64))
+                    out.append(np.asarray(objs, dtype=np.float64)[:b])
         return np.concatenate(out, axis=0)
 
     # ---------------------------------------------------------------- EDP

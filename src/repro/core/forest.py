@@ -15,9 +15,11 @@ gathers — with a backend switch mirroring core.routing:
 
   * ``"numpy"``  — the oracle; bit-equal to the recursive traversal
     (``predict_reference``), pinned by golden tests.
-  * ``"jnp"``    — jit-compiled float32 traversal (``lax.fori_loop`` over
-    depth), batch-padded to a power of two so meta-search can fuse scoring;
-    agrees with numpy up to f32 threshold rounding.
+  * ``"jnp"``    — jit-compiled float32 traversal (unrolled over depth),
+    batch-padded to a power of two so meta-search can fuse scoring; its
+    device packing has a fixed per-tree capacity (``device_shape``), so
+    refits of one forest configuration share one compile. Agrees with
+    numpy up to f32 threshold rounding.
   * ``"pallas"`` — the blocked VMEM-resident traversal kernel in
     kernels/forest (grid over batch blocks, node tensors pinned across the
     grid). It runs only through the Pallas interpreter (``interpret=True``;
@@ -228,6 +230,10 @@ def _predict_flat_jnp_fn():
 
 _JITTED_FLAT = None
 
+#: deepest ``max_depth`` whose full-tree node capacity the device packing
+#: reserves per tree (2^13 nodes; see ``RegressionForest.device_shape``)
+FULL_TREE_MAX_DEPTH = 12
+
 
 class RegressionForest:
     def __init__(self, n_trees: int = 24, max_depth: int = 9,
@@ -364,6 +370,30 @@ class RegressionForest:
             vals[ti] = np.take(fl["value_flat"], idx)
         return np.mean(vals, axis=0)
 
+    def device_shape(self) -> tuple[int, int, int]:
+        """Static shape key ``(depth, n_trees, n_nodes)`` of the device
+        packing (:meth:`jnp_tensors`).
+
+        It depends on the forest's configuration alone wherever that is
+        affordable, so every refit of a search reuses one compiled
+        traversal: each tree's node block is padded to the capacity of a
+        full tree of ``max_depth`` (2^(max_depth+1) nodes: 1024 at the
+        default 9) and the loop unrolls ``max_depth`` levels (leaves
+        self-loop, so levels past a tree's depth are the identity). Past
+        ``FULL_TREE_MAX_DEPTH`` a full tree is too large to reserve; the
+        block is then the largest tree's node count rounded up to a power
+        of two and the loop unrolls the fitted depth."""
+        fl = self._flat
+        m, t = fl["n_nodes"], len(self.trees)
+        if self.max_depth <= FULL_TREE_MAX_DEPTH:
+            return self.max_depth, t, 1 << (self.max_depth + 1)
+        return fl["depth"], t, 1 << max(0, (m - 1).bit_length())
+
+    def layout_attrs(self) -> dict[str, int]:
+        """``nodes`` (the largest tree's node count) and ``cap`` (its
+        padded block on the device): how full the device packing is."""
+        return {"nodes": self._flat["n_nodes"], "cap": self.device_shape()[2]}
+
     def jnp_tensors(self):
         """Cached f32 device tensors of the flat forest, plus its static
         shape key: ``(thrfeat, child, value), (depth, n_trees, n_nodes)``.
@@ -371,20 +401,33 @@ class RegressionForest:
         This is the packing `_predict_jnp` traverses; it is public so the
         fused meta-search (core.fused) can inline the same traversal inside
         its own jitted featurize→score pipeline without round-tripping
-        features through the host."""
+        features through the host. Each tree owns a block of ``n_nodes``
+        slots (:meth:`device_shape`); the slots past its own nodes are
+        self-looping leaves no pointer reaches, so a traversal returns what
+        the unpadded layout returns. Every dtype is converted in NumPy: the
+        one ``jnp.asarray`` per tensor is a plain transfer, with no device
+        conversion to compile."""
         import jax.numpy as jnp
 
+        key = self.device_shape()
         if self._flat_jnp is None:
             fl = self._flat
-            thrfeat = (fl["threshold_flat"].astype(np.float32) +
-                       1j * fl["feat_safe_flat"].astype(np.float32))
-            self._flat_jnp = (
-                jnp.asarray(thrfeat.astype(np.complex64)),
-                jnp.asarray(fl["child_flat"], jnp.int32),
-                jnp.asarray(fl["value_flat"], jnp.float32),
-            )
-        fl = self._flat
-        return self._flat_jnp, (fl["depth"], len(self.trees), fl["n_nodes"])
+            t, m = fl["feature"].shape
+            cap = key[2]
+            thrfeat = np.zeros((t, cap), np.complex64)
+            thrfeat[:, :m] = (fl["threshold"].astype(np.float32) + 1j *
+                              np.maximum(fl["feature"], 0).astype(np.float32))
+            child = np.empty((t, cap, 2), np.int32)
+            child[:] = np.arange(cap, dtype=np.int32)[None, :, None]
+            child[:, :m, 0] = fl["left"]
+            child[:, :m, 1] = fl["right"]
+            child += (np.arange(t, dtype=np.int32) * cap)[:, None, None]
+            value = np.zeros((t, cap), np.float32)
+            value[:, :m] = fl["value"]
+            self._flat_jnp = (jnp.asarray(thrfeat.reshape(-1)),
+                              jnp.asarray(child.reshape(-1)),
+                              jnp.asarray(value.reshape(-1)))
+        return self._flat_jnp, key
 
     def _predict_jnp(self, xn: np.ndarray) -> np.ndarray:
         import jax.numpy as jnp
@@ -392,16 +435,15 @@ class RegressionForest:
         global _JITTED_FLAT
         if _JITTED_FLAT is None:
             _JITTED_FLAT = _predict_flat_jnp_fn()
-        self.jnp_tensors()
+        tensors, (depth, n_trees, n_nodes) = self.jnp_tensors()
         b = xn.shape[0]
         pad = 1 << max(0, (b - 1).bit_length())  # bound recompiles
         xp = np.zeros((pad, xn.shape[1]), np.float32)
         xp[:b] = xn
-        fl = self._flat
-        out = _JITTED_FLAT(*self._flat_jnp, jnp.asarray(xp),
-                           depth=fl["depth"], n_trees=len(self.trees),
-                           n_nodes=fl["n_nodes"])
-        return np.asarray(out[:b], np.float64)
+        out = _JITTED_FLAT(*tensors, jnp.asarray(xp), depth=depth,
+                           n_trees=n_trees, n_nodes=n_nodes)
+        # slice on the host: an eager device slice compiles per new b
+        return np.asarray(out, np.float64)[:b]
 
     def _predict_pallas(self, xn: np.ndarray, interpret: bool = False) -> np.ndarray:
         """Blocked Pallas traversal (kernels/forest): per-tree-local node

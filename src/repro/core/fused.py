@@ -279,8 +279,8 @@ class MetaScorer:
         self._iu0, self._iu1 = self._h["iu0"], self._h["iu1"]
         (self.thrfeat, self.child, self.value), \
             (self.depth, self.n_trees, self.n_nodes) = model.jnp_tensors()
-        self.xm = jnp.asarray(model._xm, jnp.float32)
-        self.xs = jnp.asarray(model._xs, jnp.float32)
+        self.xm = jnp.asarray(model._xm.astype(np.float32))
+        self.xs = jnp.asarray(model._xs.astype(np.float32))
         if backend == "fused-pallas" and not interpret:
             raise ValueError(
                 "meta backend 'fused-pallas' runs only through the Pallas "
@@ -358,7 +358,7 @@ class MetaScorer:
                           np.full(1, self._e, np.int32),
                           depth=self.depth, n_trees=self.n_trees,
                           n_nodes=self.n_nodes)
-        return float(vals[0])
+        return float(np.asarray(vals)[0])
 
     def score_moves(self, moves: NeighborMoves) -> tuple[int, float]:
         """(argmax j, Eval of candidate j) over the neighborhood — one

@@ -238,10 +238,11 @@ def moo_stage(
             y_train.extend([res.phv] * len(res.traj))
 
             fk = _merge_forest_kwargs(forest_kwargs, forest_backend)
-            with telemetry.span("stage.fit", rows=len(x_train)):
+            with telemetry.span("stage.fit", rows=len(x_train)) as sp:
                 model = RegressionForest(seed=seed + it, **fk).fit(
                     np.stack(x_train), np.asarray(y_train)
                 )
+                sp.attrs.update(model.layout_attrs())
 
             with telemetry.span("stage.meta"):
                 d_restart = _meta_greedy(
@@ -369,9 +370,10 @@ def stage_batch(
         if x_init.shape[0]:
             # Warm surrogate: seeded past the per-iteration range (it <
             # iters_max) so the entry fit never collides with a refit seed.
-            with telemetry.span("stage.fit", rows=x_init.shape[0]):
+            with telemetry.span("stage.fit", rows=x_init.shape[0]) as sp:
                 model = RegressionForest(seed=seed + iters_max, **fk).fit(
                     x_init, y_init)
+                sp.attrs.update(model.layout_attrs())
     converged = False
     n_local = 0
     next_starts = list(starts)
@@ -417,6 +419,7 @@ def stage_batch(
                         ys = np.concatenate([y_init, ys])
                     sp.attrs["rows"] = len(xs)
                     m = RegressionForest(seed=seed + it, **fk).fit(xs, ys)
+                    sp.attrs.update(m.layout_attrs())
                 with telemetry.span("stage.meta"):
                     # One scorer per refit, shared by every chain's meta
                     # search (device-resident forest tensors transfer

@@ -100,6 +100,68 @@ def test_mixed_size_trees_pad_node_tails():
     _assert_triangle(model, rng.uniform(-1, 1, size=(33, 5)))
 
 
+def _unpadded_jnp_predict(model, xq):
+    """The jnp traversal over the unpadded flat layout: each tree's block
+    as wide as the largest tree, the loop unrolled to the fitted depth."""
+    import jax
+    import jax.numpy as jnp
+
+    fl = model._flat
+    thrfeat = (fl["threshold_flat"].astype(np.float32)
+               + 1j * fl["feat_safe_flat"].astype(np.float32))
+    xn = model._normalize(xq)
+    b = xn.shape[0]
+    xp = np.zeros((1 << max(0, (b - 1).bit_length()), xn.shape[1]),
+                  np.float32)
+    xp[:b] = xn
+    fn = jax.jit(forest_mod.flat_forest_eval,
+                 static_argnames=("depth", "n_trees", "n_nodes"))
+    out = fn(jnp.asarray(thrfeat.astype(np.complex64)),
+             jnp.asarray(fl["child_flat"].astype(np.int32)),
+             jnp.asarray(fl["value_flat"].astype(np.float32)),
+             jnp.asarray(xp), depth=fl["depth"],
+             n_trees=len(model.trees), n_nodes=fl["n_nodes"])
+    return np.asarray(out[:b], np.float64)
+
+
+@pytest.mark.parametrize("case", ["shallow", "mixed", "deep"])
+def test_padded_device_layout_matches_unpadded(case):
+    """The fixed-capacity device packing returns exactly what the unpadded
+    layout returns, within f32 rounding of the numpy oracle and with the
+    same argmax: trees shallower than ``max_depth`` (extra unrolled levels
+    spin on leaves), trees of mixed sizes (each padded to the capacity),
+    and a depth past the full-tree capacity (blocks bucketed to a power of
+    two)."""
+    if case == "shallow":
+        model, rng = _fit(n=40, f=5, n_trees=8, max_depth=9, min_leaf=4)
+        assert model._flat["depth"] < model.max_depth
+    elif case == "mixed":
+        model, rng = _fit(n=60, f=5, n_trees=12, max_depth=6, min_leaf=1)
+        sizes = {int((row != -1).sum()) for row in model._flat["feature"]}
+        assert len(sizes) > 1
+    else:
+        model, rng = _fit(n=256, f=5, n_trees=6, max_depth=16, min_leaf=1)
+        assert model.max_depth > forest_mod.FULL_TREE_MAX_DEPTH
+    depth, n_trees, cap = model.device_shape()
+    m = model._flat["n_nodes"]
+    if case == "deep":
+        assert (depth, cap) == (model._flat["depth"],
+                                1 << (m - 1).bit_length())
+    else:
+        assert (depth, cap) == (model.max_depth, 1 << (model.max_depth + 1))
+    assert cap >= m and n_trees == len(model.trees)
+    assert model.layout_attrs() == {"nodes": m, "cap": cap}
+    assert [a.shape for a in model.jnp_tensors()[0]] == [
+        (n_trees * cap,), (2 * n_trees * cap,), (n_trees * cap,)]
+
+    xq = rng.uniform(-1.5, 1.5, size=(70, 5))
+    got = model.predict(xq, backend="jnp")
+    np.testing.assert_array_equal(got, _unpadded_jnp_predict(model, xq))
+    ref = model.predict(xq, backend="numpy")
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
+    assert int(np.argmax(got)) == int(np.argmax(ref))
+
+
 def test_kernel_tolerates_extra_padded_tail_and_small_blocks():
     """Direct kernel call: growing M with explicit self-loop filler nodes
     must not change predictions, at any batch block size (incl. blocks that

@@ -190,6 +190,46 @@ def test_score_jit_one_compile_per_padded_shape():
     assert fn._cache_size() == mid
 
 
+def test_refit_reuses_compiled_scorer_and_traversal():
+    """Forests of one configuration fitted on different row counts have
+    different node counts but one device shape: the second forest adds no
+    compile to the fused scorer or to the standalone traversal at a
+    neighborhood pad the first already used (a STAGE refit compiles
+    nothing)."""
+    from repro.core import forest as forest_mod
+    from repro.core import fused as fused_mod
+
+    spec = spec_tiny()
+    small = _fit_forest(spec, n=20, seed=3)
+    large = _fit_forest(spec, n=200, seed=3)
+    assert small._flat["n_nodes"] != large._flat["n_nodes"]
+    (ts, key_s), (tl, key_l) = small.jnp_tensors(), large.jnp_tensors()
+    assert key_s == key_l == (5, 8, 64)
+    assert [a.shape for a in ts] == [a.shape for a in tl]
+    assert [a.dtype for a in ts] == [a.dtype for a in tl]
+
+    d = random_design(spec, np.random.default_rng(0))
+    xq = design_features_batch(
+        spec, [random_design(spec, np.random.default_rng(s))
+               for s in range(5)])
+
+    def run(model):
+        sc = MetaScorer(spec, model)
+        sc.score_moves(sample_neighbor_moves(
+            spec, d, np.random.default_rng(1), n_swaps=5, n_link_moves=4))
+        sc.score_base(d)
+        return model.predict(xq, backend="jnp")
+
+    run(small)
+    score, traverse = fused_mod._SCORE_JIT, forest_mod._JITTED_FLAT
+    n_score, n_trav = score._cache_size(), traverse._cache_size()
+    out = run(large)
+    assert score._cache_size() == n_score
+    assert traverse._cache_size() == n_trav
+    np.testing.assert_allclose(out, large.predict(xq, backend="numpy"),
+                               rtol=0, atol=1e-6)
+
+
 # -------------------------------------------------------------- pallas arm
 @pytest.mark.interpret
 @pytest.mark.parametrize("nsl", [(1, 0), (3, 2), (8, 8), (24, 24)])
