@@ -10,7 +10,7 @@ import json
 import os
 
 ORDER_A = ["mistral-large-123b", "gemma3-1b", "deepseek-coder-33b", "yi-6b",
-           "qwen3-moe-30b-a3b", "moonshot-v1-16b-a3b", "zamba2-2.7b",
+           "qwen3-moe-30b-a3b", "moonlight-16b-a3b", "zamba2-2.7b",
            "mamba2-1.3b", "whisper-base", "chameleon-34b"]
 ORDER_S = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
 

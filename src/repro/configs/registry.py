@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from ..models.common import ModelConfig
 from . import (chameleon_34b, deepseek_coder_33b, gemma3_1b, mamba2_1_3b,
-               mistral_large_123b, moonshot_v1_16b_a3b, qwen3_moe_30b_a3b,
+               mistral_large_123b, moonlight_16b_a3b, qwen3_moe_30b_a3b,
                whisper_base, yi_6b, zamba2_2_7b)
 from .shapes import SHAPES, WHISPER_MAX_TARGET, Shape, applicable, cell_status
 
@@ -21,7 +21,7 @@ _MODULES = {
     "deepseek-coder-33b": deepseek_coder_33b,
     "yi-6b": yi_6b,
     "qwen3-moe-30b-a3b": qwen3_moe_30b_a3b,
-    "moonshot-v1-16b-a3b": moonshot_v1_16b_a3b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     "zamba2-2.7b": zamba2_2_7b,
     "mamba2-1.3b": mamba2_1_3b,
     "whisper-base": whisper_base,
@@ -30,9 +30,14 @@ _MODULES = {
 
 ARCH_NAMES = tuple(_MODULES)
 
+#: other names that resolve to a registered config, outside ARCH_NAMES.
+#: "moonshot-v1-16b-a3b" is still named by chip_bench's deferred
+#: soc256-moonlight-train configuration and its tests.
+ALIASES = {"moonshot-v1-16b-a3b": "moonlight-16b-a3b"}
+
 
 def get_config(name: str, smoke: bool = False) -> ModelConfig:
-    mod = _MODULES[name]
+    mod = _MODULES[ALIASES.get(name, name)]
     return mod.SMOKE if smoke else mod.CONFIG
 
 
@@ -82,6 +87,6 @@ def input_specs(cfg: ModelConfig, shape: Shape) -> dict:
 
 
 __all__ = [
-    "ARCH_NAMES", "SHAPES", "applicable", "cell_status", "get_config",
-    "input_specs",
+    "ALIASES", "ARCH_NAMES", "SHAPES", "applicable", "cell_status",
+    "get_config", "input_specs",
 ]

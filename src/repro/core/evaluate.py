@@ -228,8 +228,7 @@ class Evaluator:
         if not self.delta_on:
             return self.batch([d for m in mvs for d in m.materialize_all()])
         perms, adjs, dists, nhs = [], [], [], []
-        with telemetry.span("eval.tables",
-                            moves=sum(len(mv) for mv in mvs)):
+        with self._tables_span(sum(len(mv) for mv in mvs)):
             for mv in mvs:
                 t0 = self._host_tables(mv.base)
                 for s in range(mv.swaps.shape[0]):
@@ -267,7 +266,7 @@ class Evaluator:
         k = j - s
         rem = (int(mv.rem[k, 0]), int(mv.rem[k, 1]))
         add = (int(mv.add[k, 0]), int(mv.add[k, 1]))
-        with telemetry.span("eval.tables", moves=1):
+        with self._tables_span(1):
             adj2 = mv.base.adj.copy()
             adj2[rem[0], rem[1]] = adj2[rem[1], rem[0]] = False
             adj2[add[0], add[1]] = adj2[add[1], add[0]] = True
@@ -278,6 +277,22 @@ class Evaluator:
             t = self._moved_tables(self._host_tables(mv.base), rem, add)
             self._tab_put(key, t)
 
+    @contextlib.contextmanager
+    def _tables_span(self, moves: int):
+        """The ``eval.tables`` span, with the ``delta_stats`` counts made
+        inside it: ``swaps``, ``deltas``, ``fallbacks`` and table-cache
+        ``misses``."""
+        st = self.delta_stats
+        s0 = (st["swap"], st["delta"], st["fallback"], st["table_misses"])
+        with telemetry.span("eval.tables", moves=moves) as sp:
+            try:
+                yield
+            finally:
+                sp.attrs.update(swaps=st["swap"] - s0[0],
+                                deltas=st["delta"] - s0[1],
+                                fallbacks=st["fallback"] - s0[2],
+                                misses=st["table_misses"] - s0[3])
+
     def _host_tables(self, base: Design) -> routing.HostTables:
         key = np.packbits(base.adj).tobytes()
         t = self._tab_cache.get(key)
@@ -286,8 +301,9 @@ class Evaluator:
             self.delta_stats["table_hits"] += 1
             return t
         self.delta_stats["table_misses"] += 1
-        t = routing.host_tables(design_cost_np(self.spec, base.adj),
-                                self.consts.apsp_iters)
+        with telemetry.span("tables.build", why="miss"):
+            t = routing.host_tables(design_cost_np(self.spec, base.adj),
+                                    self.consts.apsp_iters)
         self._tab_put(key, t)
         return t
 
@@ -301,7 +317,8 @@ class Evaluator:
             cost2 = t0.cost.copy()
             cost2[rem[0], rem[1]] = cost2[rem[1], rem[0]] = np.float32(routing.INF)
             cost2[add[0], add[1]] = cost2[add[1], add[0]] = w
-            return routing.host_tables(cost2, self.consts.apsp_iters)
+            with telemetry.span("tables.build", why="fallback"):
+                return routing.host_tables(cost2, self.consts.apsp_iters)
         self.delta_stats["delta"] += 1
         return t
 

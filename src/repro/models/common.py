@@ -37,6 +37,14 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
+    n_dense_layers: int = 0        # leading dense layers (MLP width d_ff)
+    n_shared_experts: int = 0      # always-on experts of width moe_d_ff
+    # Multi-head latent attention (DeepSeek-V2/V3, full-rank queries);
+    # kv_lora_rank 0 -> off.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM (Mamba-2 / SSD).
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -66,21 +74,23 @@ class ModelConfig:
     def param_count(self) -> int:
         """Analytic parameter count (roofline MODEL_FLOPS uses this)."""
         d, v = self.d_model, self.vocab
-        hd = self.resolved_head_dim
         emb = v * d * (1 if self.family != "encdec" else 1)
         head = d * v
         total = emb + head + d  # + final norm
         def attn_params():
-            return d * hd * self.n_heads + 2 * d * hd * self.n_kv_heads + \
-                hd * self.n_heads * d + 2 * d
+            if self.kv_lora_rank:   # MLA: the layer's two norms are added below
+                return self._attn_proj_params()
+            return self._attn_proj_params() + 2 * d
         def mlp_params(ff):
             return 3 * d * ff
         if self.family in ("dense", "vlm"):
             total += self.n_layers * (attn_params() + mlp_params(self.d_ff) + 2 * d)
         elif self.family == "moe":
             per = attn_params() + 2 * d + d * self.n_experts \
-                + self.n_experts * 3 * d * self.moe_d_ff
-            total += self.n_layers * per
+                + (self.n_experts + self.n_shared_experts) * 3 * d * self.moe_d_ff
+            dense = attn_params() + 2 * d + mlp_params(self.d_ff)
+            total += (self.n_layers - self.n_dense_layers) * per \
+                + self.n_dense_layers * dense
         elif self.family == "ssm":
             total += self.n_layers * (self._mamba_params() + d)
         elif self.family == "hybrid":
@@ -91,6 +101,21 @@ class ModelConfig:
             # decoder layers add cross attention
             total += self.n_layers * (2 * attn_params() + mlp_params(self.d_ff) + 3 * d)
         return int(total)
+
+    def _attn_proj_params(self) -> int:
+        """Attention projections of one layer. MLA: the query projection,
+        the joint KV down-projection to the latent plus the shared RoPE key,
+        the latent's norm, its up-projection to per-head keys and values,
+        and the output projection."""
+        d, h = self.d_model, self.n_heads
+        if not self.kv_lora_rank:
+            hd = self.resolved_head_dim
+            return d * hd * h + 2 * d * hd * self.n_kv_heads + hd * h * d
+        r = self.kv_lora_rank
+        return (d * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                + d * (r + self.qk_rope_head_dim) + r
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d)
 
     def _mamba_params(self) -> int:
         h, p, n = self.ssm_heads, self.ssm_head_dim, self.ssm_state
@@ -105,15 +130,14 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        dense_per_layer = (
-            d * self.resolved_head_dim * (self.n_heads + 2 * self.n_kv_heads)
-            + self.resolved_head_dim * self.n_heads * d + 2 * d
-            + d * self.n_experts
-        )
-        act_moe = self.top_k * 3 * d * self.moe_d_ff
+        attn = self._attn_proj_params() + 2 * d
+        moe_layer = attn + d * self.n_experts \
+            + (self.top_k + self.n_shared_experts) * 3 * d * self.moe_d_ff
+        dense_layer = attn + 3 * d * self.d_ff
         return int(
             self.vocab * d * 2 + d
-            + self.n_layers * (dense_per_layer + act_moe)
+            + (self.n_layers - self.n_dense_layers) * moe_layer
+            + self.n_dense_layers * dense_layer
         )
 
 

@@ -9,7 +9,8 @@ phase of the work::
 and records ``Span(name, t0_ns, t1_ns, parent, attrs, sid)`` on
 ``time.perf_counter_ns()``, where ``parent`` is the ``sid`` of the span
 that was open in the same thread when it began (``-1`` at top level) and
-``attrs`` holds small integer counts. Each span also enters
+``attrs`` holds small integer counts (or a short label, such as
+``tables.build``'s ``why``). Each span also enters
 ``jax.profiler.TraceAnnotation("repro.<name>", **attrs)``, so inside a
 profiler session it lands on the host plane of the trace, on the device
 ops' clock.

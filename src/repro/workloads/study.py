@@ -29,7 +29,7 @@ LLM_STUDY_SCENARIOS = (
     "yi-6b:train.fwd",
     "mistral-large-123b:train.grad_sync",
     "qwen3-moe-30b-a3b:train.fwd",
-    "moonshot-v1-16b-a3b:train.fwd",
+    "moonlight-16b-a3b:train.fwd",
     "yi-6b:serve.decode",
     "qwen3-moe-30b-a3b:serve.decode",
 )

@@ -9,9 +9,12 @@ the server, and the agnostic study unchanged.
 Volume accounting (per phase, all in bytes before normalization):
 
   * tensor-parallel activation all-reduces ride a bidirectional ring over
-    each data replica's model group (2(k-1)/k per ring all-reduce);
+    each data replica's model group (2(k-1)/k per ring all-reduce): one
+    per attention and one per dense MLP, so a MoE model's leading dense
+    layers (``n_dense_layers``) take two and its MoE layers one;
   * MoE dispatch+combine is an all-to-all over the model group — the
-    GPU<->GPU block structure the paper's traffic never had;
+    GPU<->GPU block structure the paper's traffic never had — on the MoE
+    layers only (``n_layers - n_dense_layers``);
   * FSDP weight all-gathers (training) ride a ring over each model rank's
     data group; grad-sync is an f32 ring all-reduce over the same group;
   * parameter/optimizer/KV-cache traffic goes GPU <-> its home LLC bank
@@ -19,9 +22,22 @@ Volume accounting (per phase, all in bytes before normalization):
     request:response split of `core.traffic`);
   * serving decode reads the whole KV context from the home banks every
     step — the many-to-few LLC-read pattern; SSM/hybrid archs read a
-    constant-size SSD state instead (no KV growth);
+    constant-size SSD state instead (no KV growth); MLA archs cache one
+    latent of ``kv_lora_rank + qk_rope_head_dim`` per token and layer;
   * a master host CPU feeds inputs and drains metrics (the §3 "master
     core" analogue), with faint background control on the other CPUs.
+
+Stated assumptions of the MoE accounting (no public trace fixes them):
+
+  * the mesh is ``(data, model)`` (`derive_mesh`) and expert parallelism
+    rides the model axis; there is no separate expert axis;
+  * dispatch is per (token, expert): each of a shard's tokens sends one
+    activation to each of its ``top_k`` experts and gets one back, and
+    routing is balanced, so a (k-1)/k share of it leaves the shard, spread
+    uniformly over the other k-1 shards;
+  * shared experts run where the token is (their weights are FSDP-sharded
+    and gathered with the rest), so they add parameters — gathered and
+    read from the home banks — but no all-to-all and no all-reduce.
 
 The result is normalized to unit sum and scaled by a per-phase injection
 intensity — exactly the `core/traffic.py` relative flits/cycle convention
@@ -32,7 +48,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.configs.registry import ARCH_NAMES, get_config
+from repro.configs.registry import ALIASES, ARCH_NAMES, get_config
 from repro.configs.shapes import SHAPES
 from repro.core.problem import SystemSpec
 from repro.core.traffic import TrafficValidationError
@@ -92,7 +108,7 @@ def parse_scenario(name: str) -> tuple[str, str]:
 
 
 def check_scenario(arch: str, phase: str) -> None:
-    if arch not in ARCH_NAMES:
+    if arch not in ARCH_NAMES and arch not in ALIASES:
         raise TrafficValidationError(
             f"unknown model {arch!r}; known: {', '.join(ARCH_NAMES)}")
     if phase not in PHASES:
@@ -104,7 +120,9 @@ def check_scenario(arch: str, phase: str) -> None:
 def _tp_allreduces(cfg) -> int:
     """Activation all-reduces over the model group per forward pass."""
     if cfg.family == "moe":
-        return cfg.n_layers                      # attn out; MLP is all-to-all
+        # attn out on every layer (a MoE MLP is an all-to-all), MLP out on
+        # the leading dense layers
+        return cfg.n_layers + cfg.n_dense_layers
     if cfg.family == "ssm":
         return cfg.n_layers                      # out_proj only
     if cfg.family == "hybrid":
@@ -131,7 +149,12 @@ def _n_blocks(cfg) -> int:
 
 
 def _kv_bytes_per_token(cfg) -> float:
-    """KV bytes appended per token across the whole model (pre-TP-shard)."""
+    """KV bytes appended per token across the whole model (pre-TP-shard):
+    keys and values per KV head, or MLA's one latent (compressed KV plus
+    the shared RoPE key) per layer."""
+    if cfg.kv_lora_rank:
+        return _attention_sites(cfg) * \
+            (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * BYTES_ACT
     return 2.0 * _attention_sites(cfg) * cfg.n_kv_heads * \
         cfg.resolved_head_dim * BYTES_ACT
 
@@ -245,8 +268,8 @@ def traffic_from_model(cfg, mapping: Mapping, phase: str) -> np.ndarray:
     n_ar = _tp_allreduces(cfg)
     a2a_remote = 0.0
     if cfg.family == "moe" and cfg.top_k:
-        a2a_remote = 2.0 * cfg.n_layers * toks * cfg.top_k * d * \
-            BYTES_ACT * (tp - 1) / max(tp, 1)
+        a2a_remote = 2.0 * (cfg.n_layers - cfg.n_dense_layers) * toks * \
+            cfg.top_k * d * BYTES_ACT * (tp - 1) / max(tp, 1)
 
     model_groups = [mapping.gpu_ids[di, :] for di in range(dp)]
     data_groups = [mapping.gpu_ids[:, mi] for mi in range(tp)]

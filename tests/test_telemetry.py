@@ -17,7 +17,7 @@ import pytest
 
 from repro import telemetry
 from repro.core.evaluate import Evaluator
-from repro.core.problem import spec_tiny
+from repro.core.problem import spec_16, spec_tiny
 from repro.noc import Budget, NocProblem, run
 
 #: every span name a seeded stage_batch with host table deltas records
@@ -25,7 +25,7 @@ SEARCH_SPANS = {
     "noc.run", "stage.iter", "stage.features", "stage.fit", "stage.meta",
     "meta.step", "local.step", "local.sample", "local.select",
     "local.archive", "eval.dispatch", "eval.pack", "eval.wait",
-    "eval.tables", "jit.compile", "jit.lower",
+    "eval.tables", "tables.build", "jit.compile", "jit.lower",
 }
 SMALL = {"n_starts": 2, "iters_max": 3, "n_swaps": 6, "n_link_moves": 6,
          "max_local_steps": 6}
@@ -174,6 +174,30 @@ def test_seeded_stage_batch_records_every_phase():
         if s.name == "meta.step":
             assert by_sid[s.parent].name == "stage.meta"
             assert s.attrs["cands"] > 0
+
+
+def test_delta_path_counters_sum_to_delta_stats():
+    """The counts on ``eval.tables`` add up to the evaluator's
+    ``delta_stats``, and each full host build is a ``tables.build`` span
+    under it, named by why it ran."""
+    problem = NocProblem(spec=spec_16(), traffic="BFS", case="case5")
+    ev = Evaluator(problem.spec, problem.traffic_matrix(), delta="on")
+    t0 = time.perf_counter_ns()
+    run(problem, "stage_batch", budget=Budget(max_evals=120, seed=3),
+        config=SMALL, ev=ev)
+    spans = _since(t0)
+    tables = [s for s in spans if s.name == "eval.tables"]
+    st = ev.delta_stats
+    assert st["swap"] and st["delta"]
+    for attr, key in (("swaps", "swap"), ("deltas", "delta"),
+                      ("fallbacks", "fallback"), ("misses", "table_misses")):
+        assert sum(s.attrs[attr] for s in tables) == st[key], attr
+    builds = [s for s in spans if s.name == "tables.build"]
+    by_sid = {s.sid: s for s in spans}
+    assert all(by_sid[s.parent].name == "eval.tables" for s in builds)
+    assert sum(s.attrs["why"] == "miss" for s in builds) == st["table_misses"]
+    assert (sum(s.attrs["why"] == "fallback" for s in builds)
+            == st["fallback"])
 
 
 def test_spans_leave_the_search_unchanged():
