@@ -184,7 +184,7 @@ class Evaluator:
             ndev = self.mesh.devices.size
             if pad % ndev:
                 pad = -(-pad // ndev) * ndev
-        with telemetry.span("eval.dispatch", rows=b, padded=pad):
+        with telemetry.span("eval.dispatch", rows=b, padded=pad) as sp:
             with telemetry.span("eval.pack"):
                 perms = np.stack([d.perm for d in designs]
                                  + [designs[-1].perm] * (pad - b))
@@ -206,6 +206,7 @@ class Evaluator:
                 # slice would compile once per new row count b.
                 aux = {k: np.asarray(v)[:b] for k, v in aux.items()}
                 objs = np.asarray(objs, dtype=np.float64)[:b]
+            self._note_walk(sp, aux.pop("walk_steps"))
         return objs, aux
 
     # -------------------------------------------------------------- moves
@@ -345,7 +346,7 @@ class Evaluator:
             pad = 1 << max(0, (b - 1).bit_length())
             sl = slice(i, i + b)
             tail = pad - b
-            with telemetry.span("eval.dispatch", rows=b, padded=pad):
+            with telemetry.span("eval.dispatch", rows=b, padded=pad) as sp:
                 with telemetry.span("eval.pack"):
                     pj = jnp.asarray(np.stack(
                         perms[sl] + [perms[i + b - 1]] * tail))
@@ -355,12 +356,20 @@ class Evaluator:
                         dists[sl] + [dists[i + b - 1]] * tail))
                     nj = jnp.asarray(np.stack(
                         nhs[sl] + [nhs[i + b - 1]] * tail))
-                objs, _ = self._eval_fn(pj, aj, self.f, dj, nj)
+                objs, aux = self._eval_fn(pj, aj, self.f, dj, nj)
                 self.n_evals += b
                 self.n_calls += 1
                 with telemetry.span("eval.wait"):
                     out.append(np.asarray(objs, dtype=np.float64)[:b])
+                    self._note_walk(sp, np.asarray(aux["walk_steps"]))
         return np.concatenate(out, axis=0)
+
+    def _note_walk(self, span, walk_steps: np.ndarray) -> None:
+        """Give a dispatch's span the steps its path walk ran
+        (``walk_steps``: the batch's longest walk, which is how long the
+        batched loop ran) and the cap (``walk_cap``, ``max_hops``)."""
+        span.attrs.update(walk_steps=int(walk_steps.max()),
+                          walk_cap=self.consts.max_hops)
 
     # ---------------------------------------------------------------- EDP
     def edp(self, d: Design) -> float:

@@ -139,14 +139,16 @@ def evaluate_with_tables(
     dist: jnp.ndarray,   # (N, N) APSP distances for this design
     nh: jnp.ndarray,     # (N, N) int32 next hops for this design
 ):
-    """Objectives given precomputed routing tables (Eqs. 1-10)."""
+    """Objectives given precomputed routing tables (Eqs. 1-10). ``aux``
+    holds ``connected``, ``net_lat`` and ``walk_steps``, the steps the path
+    walk ran (``routing.walk_paths``)."""
     n = perm.shape[0]
     full_adj = adj | c.vadj
     # Traffic between SLOTS under this placement.
     f_slots = f[perm][:, perm] * (1.0 - jnp.eye(n))
 
     # ---- routing ---------------------------------------------------- Eq. 1
-    hops, delay, util_d, visits, all_done = routing.walk_paths(
+    hops, delay, util_d, visits, all_done, steps = routing.walk_paths(
         nh, c.link_delay, f_slots.astype(jnp.float32), c.max_hops
     )
     connected = jnp.all(dist < routing.INF / 2) & all_done
@@ -200,7 +202,7 @@ def evaluate_with_tables(
     # the paper's network-EDP metric (§6.1), not as a search objective.
     total_f = jnp.sum(f_slots) + 1e-12
     net_lat = jnp.sum((c.router_stages * hops + delay) * f_slots) / total_f
-    aux = {"connected": connected, "net_lat": net_lat}
+    aux = {"connected": connected, "net_lat": net_lat, "walk_steps": steps}
     return objs, aux
 
 

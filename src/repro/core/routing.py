@@ -175,22 +175,36 @@ def walk_paths(
     f: jnp.ndarray,           # (N, N) traffic between SLOTS (already permuted)
     max_hops: int,
 ):
-    """Walk every (src, dst) pair simultaneously for ``max_hops`` steps.
+    """Walk every (src, dst) pair simultaneously, one hop per step, until
+    the last pair has arrived or ``max_hops`` steps have run.
 
-    Returns (hops, delay, util, visits, all_done):
+    ``max_hops`` is the validity cap: a pair still on its way then (a
+    routing loop, or a path longer than the cap) leaves ``all_done`` false.
+    A step after a pair has arrived adds exact zeros and leaves the pair
+    where it is, so stopping at the last arrival gives the same outputs,
+    bit for bit, as walking all ``max_hops`` steps. Under ``vmap`` the
+    batch runs until its last pair has arrived, each design keeping its
+    own outputs and ``steps``.
+
+    Returns (hops, delay, util, visits, all_done, steps):
       hops   (N, N) — links on the path i->j
       delay  (N, N) — sum of wire delays along the path
       util   (N, N) — f-weighted directed link usage  (Eq. 2 accumulation)
       visits (N,)   — f-weighted router traversals (src router included at
                       each step; dst router added at completion)  (Eq. 8)
       all_done ()   — bool: every pair reached its destination
+      steps  ()     — int32: steps walked, the longest path or ``max_hops``
     """
     n = nh.shape[0]
     src = jnp.arange(n, dtype=jnp.int32)[:, None] * jnp.ones((1, n), jnp.int32)
     dst = jnp.arange(n, dtype=jnp.int32)[None, :] * jnp.ones((n, 1), jnp.int32)
 
-    def body(_, carry):
-        cur, hops, delay, util, visits = carry
+    def moving(state):
+        t, cur = state[:2]
+        return (t < max_hops) & jnp.any(cur != dst)
+
+    def body(state):
+        t, cur, hops, delay, util, visits = state
         done = cur == dst
         nxt = nh[cur, dst]
         w = jnp.where(done, 0.0, f)
@@ -199,25 +213,21 @@ def walk_paths(
         delay = delay + jnp.where(done, 0.0, link_delay[cur, nxt])
         hops = hops + jnp.where(done, 0, 1)
         cur = jnp.where(done, cur, nxt)
-        return cur, hops, delay, util, visits
+        return t + 1, cur, hops, delay, util, visits
 
-    # The initial carry is derived from ``nh`` so that it has nh's type,
-    # varying manual axes included: under shard_map the body's outputs
-    # vary over the device axis, and a carry of plain constants would not.
+    # The initial carry, the step counter included, is derived from ``nh``
+    # so that it has nh's type, varying manual axes included: under
+    # shard_map the body's outputs and the loop's predicate vary over the
+    # device axis, and a carry of plain constants would not.
     zero = nh * 0
     zero_f = zero.astype(jnp.float32)
-    cur0 = src + zero
-    hops0 = zero
-    delay0 = zero_f
-    util0 = zero_f
-    visits0 = zero_f[0]
-    cur, hops, delay, util, visits = jax.lax.fori_loop(
-        0, max_hops, body, (cur0, hops0, delay0, util0, visits0)
-    )
+    steps, cur, hops, delay, util, visits = jax.lax.while_loop(
+        moving, body, (zero[0, 0], src + zero, zero, zero_f, zero_f,
+                       zero_f[0]))
     all_done = jnp.all(cur == dst)
     # Destination router traversal (h hops -> h+1 routers).
     visits = visits + f.sum(axis=0)
-    return hops, delay, util, visits, all_done
+    return hops, delay, util, visits, all_done, steps
 
 
 def routing_tables(cost: jnp.ndarray, n_iters: int):

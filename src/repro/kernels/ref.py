@@ -54,7 +54,7 @@ def walk_accumulate_ref(nh, f, delay, *, max_hops: int):
     walk and adapts output dtypes to the kernel contract."""
     from repro.core.routing import walk_paths
 
-    hops, dsum, util, visits, _ = walk_paths(
+    hops, dsum, util, visits, _, _ = walk_paths(
         jnp.asarray(nh, jnp.int32), jnp.asarray(delay, jnp.float32),
         jnp.asarray(f, jnp.float32), max_hops,
     )

@@ -1,14 +1,26 @@
 """Routing (APSP/next-hop/walk) vs networkx oracle + objective sanity."""
 
+import time
+from functools import partial
+from pathlib import Path
+
+import jax
 import jax.numpy as jnp
 import networkx as nx
 import numpy as np
 import pytest
 
-from repro.core import (Evaluator, random_design, spec_16, spec_64, spec_tiny,
-                        traffic_matrix)
+from repro import telemetry
+from repro.core import (Evaluator, random_design, sample_neighbors, spec_16,
+                        spec_64, spec_tiny, traffic_matrix)
 from repro.core import routing
-from repro.core.objectives import make_consts, peak_temperature_celsius
+from repro.core.objectives import (design_cost_np, make_consts,
+                                   peak_temperature_celsius)
+from repro.kernels.ref import walk_accumulate_np
+
+#: objective rows and ``net_lat`` of the ``WALK_CASES`` batches, recorded
+#: from the evaluator when its walk ran a fixed ``max_hops`` steps
+GOLDEN = Path(__file__).parent / "data" / "walk_golden.npz"
 
 
 def _cost_matrix(spec, d):
@@ -49,7 +61,7 @@ def test_walk_consistent_with_dist():
     cost, c = _cost_matrix(spec, d)
     dist, nh = routing.routing_tables(cost, c.apsp_iters)
     f = jnp.ones((spec.n_tiles, spec.n_tiles), jnp.float32)
-    hops, delay, util, visits, all_done = routing.walk_paths(
+    hops, delay, util, visits, all_done, _ = routing.walk_paths(
         nh, c.link_delay, f, c.max_hops
     )
     assert bool(all_done)
@@ -65,7 +77,7 @@ def test_walk_utilization_conservation():
     dist, nh = routing.routing_tables(cost, c.apsp_iters)
     rng = np.random.default_rng(0)
     f = jnp.asarray(rng.uniform(size=(8, 8)) * (1 - np.eye(8)), jnp.float32)
-    hops, delay, util, visits, all_done = routing.walk_paths(
+    hops, delay, util, visits, all_done, _ = routing.walk_paths(
         nh, c.link_delay, f, c.max_hops
     )
     assert float(jnp.sum(util)) == pytest.approx(
@@ -75,6 +87,97 @@ def test_walk_utilization_conservation():
     assert float(jnp.sum(visits)) == pytest.approx(
         float(jnp.sum(f * hops) + jnp.sum(f)), rel=1e-5
     )
+
+
+def _walk_batch(spec, seed, n_random):
+    """``n_random`` random designs, then 2 swaps and 2 link moves of the
+    first."""
+    rng = np.random.default_rng(seed)
+    ds = [random_design(spec, rng) for _ in range(n_random)]
+    return ds + sample_neighbors(spec, ds[0], rng, 2, 2)
+
+
+def _loop_one_pair(nh):
+    """Next hops in which one pair bounces between two routers for ever."""
+    nh = nh.copy()
+    i, j = 0, int(np.argmax(nh[0] != np.arange(nh.shape[0])))
+    a = int(nh[i, j])
+    nh[a, j] = i
+    return nh
+
+
+# name: (spec, seed, random designs, row whose walk never arrives)
+WALK_CASES = {"spec_16": (spec_16, 16, 3, None),
+              "spec_64": (spec_64, 64, 2, None),
+              "spec_16_unreachable": (spec_16, 16, 3, 3)}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_early_exit_walk(case):
+    """The walk stops when the batch's last pair arrives, and gives the
+    rows the fixed ``max_hops`` walk gave, bit for bit; the batches pad to
+    a power of two with copies of their last design. A pair that never
+    arrives keeps the loop running to the cap, ``all_done`` false and its
+    design's row +INF, and leaves the other rows as they were."""
+    spec_fn, seed, n_random, stuck = WALK_CASES[case]
+    spec = spec_fn()
+    c = make_consts(spec)
+    f = traffic_matrix(spec, "BP")
+    ds = _walk_batch(spec, seed, n_random)
+    tabs = [routing.host_tables(design_cost_np(spec, d.adj), c.apsp_iters)
+            for d in ds]
+    nhs = [t.nh for t in tabs]
+    if stuck is not None:
+        nhs[stuck] = _loop_one_pair(nhs[stuck])
+    golden = np.load(GOLDEN)
+    gold = golden[case.removesuffix("_unreachable") + ".objs"]
+    ok = np.arange(len(ds)) != stuck
+
+    ev = Evaluator(spec, f)
+    if stuck is None:
+        objs, aux = ev.batch_aux(ds)
+        np.testing.assert_array_equal(objs, gold)
+        np.testing.assert_array_equal(
+            aux["net_lat"], golden[case + ".net_lat"])
+    t0 = time.perf_counter_ns()
+    rows = ev._eval_from_tables([d.perm for d in ds], [d.adj for d in ds],
+                                [t.dist for t in tabs], nhs)
+    np.testing.assert_array_equal(rows[ok], gold[ok])
+    assert np.all(rows[~ok] == routing.INF)
+
+    # the walk itself, on the padded batch, against the numpy oracle that
+    # walks every pair to its destination or to the cap
+    pad = 1 << (len(ds) - 1).bit_length()
+    perms = [d.perm for d in ds] + [ds[-1].perm] * (pad - len(ds))
+    nh_b = np.stack(nhs + [nhs[-1]] * (pad - len(ds)))
+    f_b = np.stack([f[p][:, p] * (1 - np.eye(spec.n_tiles)) for p in perms]
+                   ).astype(np.float32)
+    hops, delay, util, visits, done, steps = jax.vmap(
+        partial(routing.walk_paths, max_hops=c.max_hops),
+        in_axes=(0, None, 0))(jnp.asarray(nh_b), c.link_delay,
+                              jnp.asarray(f_b))
+    longest = []
+    for k in range(pad):
+        h, dl, u, v = walk_accumulate_np(nh_b[k], f_b[k], c.link_delay,
+                                         max_hops=c.max_hops)
+        np.testing.assert_array_equal(np.asarray(hops[k]), h)
+        np.testing.assert_allclose(np.asarray(delay[k]), dl, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(util[k]), u, rtol=1e-5,
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(visits[k]), v, rtol=1e-5)
+        assert bool(done[k]) == (k != stuck)
+        assert int(steps[k]) == int(h.max())
+        longest.append(int(h.max()))
+    # the batched loop ran as long as the batch's longest path: the cap
+    # where a pair never arrives, far below it where all do
+    (disp,) = [s for s in telemetry.spans()
+               if s.name == "eval.dispatch" and s.t0_ns >= t0]
+    assert disp.attrs["walk_steps"] == max(longest)
+    assert disp.attrs["walk_cap"] == c.max_hops
+    if stuck is None:
+        assert max(longest) < c.max_hops // 2
+    else:
+        assert max(longest) == c.max_hops
 
 
 def test_mesh_objectives_valid_and_positive():

@@ -200,6 +200,42 @@ def test_delta_path_counters_sum_to_delta_stats():
             == st["fallback"])
 
 
+@pytest.mark.parametrize("path", ["batch_aux", "batch_moves"])
+def test_dispatch_spans_carry_the_walks_steps_and_cap(path):
+    """Both dispatch paths, the dense ``batch_aux`` and the delta path's
+    ``batch_moves``, give each ``eval.dispatch`` span ``walk_steps``, the
+    longest walk of its batch, and ``walk_cap``, ``max_hops``."""
+    from repro.core import routing
+    from repro.core.objectives import design_cost_np, make_consts
+    from repro.core.problem import random_design, sample_neighbor_moves
+    from repro.kernels.ref import walk_accumulate_np
+
+    problem = NocProblem(spec=spec_16(), traffic="BFS", case="case5")
+    spec = problem.spec
+    ev = Evaluator(spec, problem.traffic_matrix(), delta="on", max_batch=8)
+    rng = np.random.default_rng(7)
+    mv = sample_neighbor_moves(spec, random_design(spec, rng), rng, 6, 6)
+    ds = mv.materialize_all()
+    c = make_consts(spec)
+    longest = []
+    for d in ds:
+        nh = routing.host_tables(design_cost_np(spec, d.adj),
+                                 c.apsp_iters).nh
+        h = walk_accumulate_np(nh, np.ones(nh.shape), c.link_delay,
+                               max_hops=c.max_hops)[0]
+        longest.append(int(h.max()))
+    t0 = time.perf_counter_ns()
+    if path == "batch_aux":
+        ev.batch_aux(ds)
+    else:
+        ev.batch_moves(mv)
+    disp = _since(t0, "eval.dispatch")
+    assert [s.attrs["rows"] for s in disp] == [8, len(ds) - 8]
+    assert [s.attrs["walk_steps"] for s in disp] == [max(longest[:8]),
+                                                     max(longest[8:])]
+    assert all(s.attrs["walk_cap"] == spec.max_hops for s in disp)
+
+
 def test_spans_leave_the_search_unchanged():
     """The same seeded search twice: same front, same span sequence."""
     out = []
