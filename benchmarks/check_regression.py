@@ -38,8 +38,6 @@ TRACKED = (
     "forest_predict_4k_numpy_us",
     "forest_predict_4k_jnp_us",
     "forest_reference_4k_us",
-    "forest_pallas_4k_us",
-    "forest_pallas_interp_512_us",
     "stage_meta_search_us_per_step",
     "stage_fused_us_per_step",
     "stage_dist_4w_us",

@@ -117,27 +117,6 @@ def main(reduced: bool = False) -> None:
     bench["forest_reference_4k_us"] = t_ref * 1e6
     bench["forest_speedup_4k"] = t_ref / t_best
 
-    # Pallas forest traversal (kernels/forest): on TPU backend="pallas"
-    # runs the blocked VMEM-resident kernel; on this CPU container it falls
-    # back to jnp (one-time warning), so the row tracks the pallas *entry
-    # path* on whatever it resolves to — the note records which. The
-    # interpret row forces the real kernel body through the Pallas
-    # interpreter: a correctness-adjacent latency smoke of the TPU code
-    # path that runs everywhere.
-    from repro.core.forest import resolve_forest_backend
-    resolved = resolve_forest_backend("pallas", batch=4096)
-    forest.predict(xq, backend="pallas")  # warm compile (+ fallback warning)
-    t_pal = _min_of(lambda: forest.predict(xq, backend="pallas"))
-    row("forest_pallas_4k", t_pal * 1e6, f"resolved={resolved}")
-    bench["forest_pallas_4k_us"] = t_pal * 1e6
-    xs = xq[:512]
-    forest.predict(xs, backend="pallas", interpret=True)  # warm
-    t_int = _min_of(
-        lambda: forest.predict(xs, backend="pallas", interpret=True))
-    row("forest_pallas_interp_512", t_int * 1e6,
-        "interpret_smoke;block_b=128")
-    bench["forest_pallas_interp_512_us"] = t_int * 1e6
-
     # Meta-search step: batched feature extraction + one flat predict per
     # sampled neighborhood (no objective evaluations are spent here).
     srng = np.random.default_rng(2)

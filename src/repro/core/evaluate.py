@@ -43,8 +43,7 @@ _BATCH_BUDGET_BYTES = 512 << 20
 
 #: ambient SPMD mesh — set via :func:`spmd_scope`; Evaluators constructed
 #: inside the scope run their batch pipeline as one shard_map program over
-#: it (the same contextvar-at-construction pattern as repro.dist.worker's
-#: cooperative deadline).
+#: it (read once, at construction).
 _SPMD_MESH: contextvars.ContextVar = contextvars.ContextVar(
     "repro_core_spmd_mesh", default=None)
 
@@ -91,7 +90,11 @@ class Evaluator:
     ``backend`` selects the batched-APSP implementation (see core.routing):
     ``"auto"`` (default) resolves to the Pallas kernel on TPU and jnp
     elsewhere. ``interpret=True`` forces the Pallas kernel through the
-    interpreter — CPU-only correctness testing of the TPU path."""
+    interpreter — CPU-only correctness testing of the TPU path.
+
+    Limits (a budget, a deadline) are armed with :meth:`limited` and
+    checked once at each public dispatch entry: ``batch_aux`` (hence
+    ``batch`` and ``__call__``), ``batch_moves`` and ``edp``."""
 
     def __init__(self, spec: SystemSpec, f: np.ndarray, *,
                  backend: str = "auto", interpret: bool = False,
@@ -137,6 +140,7 @@ class Evaluator:
                             "table_hits": 0, "table_misses": 0}
         self.n_evals = 0  # evaluation counter (search-cost accounting)
         self.n_calls = 0  # XLA dispatches (batching-efficiency accounting)
+        self._limits: list = []  # armed checks, see limited()
 
     def _build_spmd_fn(self):
         """One jitted shard_map program for the whole batch pipeline: each
@@ -155,6 +159,24 @@ class Evaluator:
                                      in_specs=(p, p, P()), out_specs=(p, p),
                                      check_vma=not self.interpret))
 
+    # ------------------------------------------------------------- limits
+    @contextlib.contextmanager
+    def limited(self, check):
+        """Arm ``check`` (a zero-argument callable that raises once its
+        limit is reached) for the duration of the block. Every public
+        dispatch entry runs the armed checks, newest first, once per call
+        and before it dispatches anything, so a tripped limit costs no
+        evaluation. Disarmed on exit, also on an exception."""
+        self._limits.append(check)
+        try:
+            yield
+        finally:
+            self._limits.pop()
+
+    def _check_limits(self) -> None:
+        for check in reversed(self._limits):
+            check()
+
     # ------------------------------------------------------------- single
     def __call__(self, d: Design) -> np.ndarray:
         return self.batch([d])[0]
@@ -167,11 +189,16 @@ class Evaluator:
     def batch_aux(self, designs: list[Design]) -> tuple[np.ndarray, dict]:
         if not designs:
             return np.zeros((0, N_OBJ)), {"net_lat": np.zeros((0,))}
+        self._check_limits()
+        return self._batch_aux(designs)
+
+    def _batch_aux(self, designs: list[Design]) -> tuple[np.ndarray, dict]:
+        """``batch_aux`` of a non-empty batch, without the limit checks."""
         if self.max_batch is not None and len(designs) > self.max_batch:
             # Bound the transient (chunk, N, N, N) min-plus broadcast when a
             # multi-chain driver concatenates many neighborhoods.
             outs, auxes = zip(*(
-                self.batch_aux(designs[i:i + self.max_batch])
+                self._batch_aux(designs[i:i + self.max_batch])
                 for i in range(0, len(designs), self.max_batch)))
             return (np.concatenate(outs, axis=0),
                     {k: np.concatenate([a[k] for a in auxes], axis=0)
@@ -226,8 +253,10 @@ class Evaluator:
         mvs = [m for m in mvs if len(m)]
         if not mvs:
             return np.zeros((0, N_OBJ))
+        self._check_limits()
         if not self.delta_on:
-            return self.batch([d for m in mvs for d in m.materialize_all()])
+            return self._batch_aux(
+                [d for m in mvs for d in m.materialize_all()])[0]
         perms, adjs, dists, nhs = [], [], [], []
         with self._tables_span(sum(len(mv) for mv in mvs)):
             for mv in mvs:
@@ -375,5 +404,6 @@ class Evaluator:
     def edp(self, d: Design) -> float:
         """Network EDP = network latency x network energy (paper §6.1; the
         analytic variant — core/netsim.py provides the simulated one)."""
-        objs, aux = self.batch_aux([d])
+        self._check_limits()
+        objs, aux = self._batch_aux([d])
         return float(aux["net_lat"][0] * objs[0, 3])

@@ -20,11 +20,6 @@ gathers — with a backend switch mirroring core.routing:
     device packing has a fixed per-tree capacity (``device_shape``), so
     refits of one forest configuration share one compile. Agrees with
     numpy up to f32 threshold rounding.
-  * ``"pallas"`` — the blocked VMEM-resident traversal kernel in
-    kernels/forest (grid over batch blocks, node tensors pinned across the
-    grid). It runs only through the Pallas interpreter (``interpret=True``;
-    conformance tests): the TPU compiler refuses its per-tree gathers, so
-    no default path selects it and a request without ``interpret`` raises.
   * ``"auto"``   — jnp on an accelerator (TPU or GPU), numpy/jnp by batch
     size on CPU (DESIGN.md §4.4).
 """
@@ -35,7 +30,7 @@ from functools import partial
 
 import numpy as np
 
-FOREST_BACKENDS = ("auto", "numpy", "jnp", "pallas")
+FOREST_BACKENDS = ("auto", "numpy", "jnp")
 
 
 def check_forest_backend(backend: str | None, *,
@@ -53,18 +48,12 @@ def check_forest_backend(backend: str | None, *,
 
 
 def resolve_forest_backend(backend: str | None = None,
-                           batch: int | None = None,
-                           interpret: bool = False) -> str:
+                           batch: int | None = None) -> str:
     """Resolve ``backend`` (default ``"auto"``) to a concrete one.
 
-    ``auto`` never picks the Pallas kernel. Mosaic cannot lower its gathers
-    for the TPU (``take_along_axis`` of a (T, M) node table by (T, B)
-    pointers: the index shape must equal the operand shape), so on a TPU,
-    as on a GPU, ``auto`` is the jitted jnp traversal. On CPU it picks
-    numpy for small (neighborhood-sized) batches, where per-call dispatch
-    dominates, and jnp for large ones. An explicit ``"pallas"`` needs
-    ``interpret=True`` (the Pallas interpreter runs the kernel anywhere)
-    and raises ``ValueError`` without it, on every platform."""
+    On a TPU, as on a GPU, ``auto`` is the jitted jnp traversal. On CPU it
+    picks numpy for small (neighborhood-sized) batches, where per-call
+    dispatch dominates, and jnp for large ones."""
     b = backend if backend is not None else "auto"
     check_forest_backend(b)
     if b == "auto":
@@ -74,11 +63,6 @@ def resolve_forest_backend(backend: str | None = None,
             b = "jnp"
         else:
             b = "numpy" if batch is not None and batch < 512 else "jnp"
-    if b == "pallas" and not interpret:
-        raise ValueError(
-            "forest backend 'pallas' runs only through the Pallas "
-            "interpreter (interpret=True): the TPU compiler cannot lower "
-            "its gathers; use 'jnp' or 'auto'")
     return b
 
 
@@ -248,7 +232,6 @@ class RegressionForest:
         self._xm = self._xs = None
         self._flat = None        # packed (T, M) numpy tensors
         self._flat_jnp = None    # f32 device copies, built on first jnp call
-        self._flat_pallas = None  # kernel-layout copies, first pallas call
 
     def fit(self, x: np.ndarray, y: np.ndarray) -> "RegressionForest":
         x = np.asarray(x, np.float64)
@@ -305,25 +288,17 @@ class RegressionForest:
             "depth": depth, "n_nodes": m,
         }
         self._flat_jnp = None
-        self._flat_pallas = None
 
     # -------------------------------------------------------------- predict
     def _normalize(self, x: np.ndarray) -> np.ndarray:
         x = np.atleast_2d(np.asarray(x, np.float64))
         return (x - self._xm) / self._xs
 
-    def predict(self, x: np.ndarray, backend: str | None = None,
-                interpret: bool = False) -> np.ndarray:
-        """(B,) forest mean via the flat vectorized traversal.
-
-        ``interpret`` only affects the pallas backend: it runs the blocked
-        kernel through the Pallas interpreter so the TPU code path is
-        exercised on CPU (tests, CI smoke)."""
+    def predict(self, x: np.ndarray, backend: str | None = None) -> np.ndarray:
+        """(B,) forest mean via the flat vectorized traversal."""
         xn = self._normalize(x)
         b = resolve_forest_backend(backend if backend is not None else self.backend,
-                                   batch=xn.shape[0], interpret=interpret)
-        if b == "pallas":
-            return self._predict_pallas(xn, interpret=interpret)
+                                   batch=xn.shape[0])
         if b == "jnp":
             return self._predict_jnp(xn)
         return self._predict_numpy(xn)
@@ -444,37 +419,3 @@ class RegressionForest:
                            n_trees=n_trees, n_nodes=n_nodes)
         # slice on the host: an eager device slice compiles per new b
         return np.asarray(out, np.float64)[:b]
-
-    def _predict_pallas(self, xn: np.ndarray, interpret: bool = False) -> np.ndarray:
-        """Blocked Pallas traversal (kernels/forest): per-tree-local node
-        tensors resident in VMEM, grid over batch blocks. Branch decisions
-        match the jnp twin exactly (same f32 compares); both agree with the
-        f64 numpy oracle up to f32 threshold rounding."""
-        import jax.numpy as jnp
-
-        from ..kernels import forest as _forest  # deferred: keeps core importable sans kernels
-
-        if self._flat_pallas is None:
-            fl = self._flat
-            t, m = fl["feature"].shape
-            child = np.empty((t, 2 * m), np.int32)
-            child[:, 0::2] = fl["left"]
-            child[:, 1::2] = fl["right"]
-            self._flat_pallas = (
-                jnp.asarray(fl["threshold"], jnp.float32),
-                jnp.asarray(np.maximum(fl["feature"], 0), jnp.int32),
-                jnp.asarray(child),
-                jnp.asarray(fl["value"], jnp.float32),
-            )
-        # Pad the batch to a block multiple *outside* the jitted call so
-        # the jit cache keys on the quantized shape — one compile per
-        # forest shape, not one per raw neighborhood size (the same
-        # retrace-bounding trick as _predict_jnp's power-of-two padding).
-        b = xn.shape[0]
-        bp = -(-b // _forest.BLOCK_B) * _forest.BLOCK_B
-        xp = np.zeros((bp, xn.shape[1]), np.float32)
-        xp[:b] = xn
-        out = _forest.forest_predict(
-            *self._flat_pallas, jnp.asarray(xp),
-            depth=self._flat["depth"], interpret=interpret)[:b]
-        return np.asarray(out, np.float64)

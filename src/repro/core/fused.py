@@ -23,7 +23,7 @@ exactly the base value and can never win an accept test (strict ``>``);
 the host argmax additionally only looks at the real prefix.
 
 Feature math mirrors features.design_features_batch exactly, in f32 (the
-same precision the forest's jnp/pallas twins traverse at). Per-slot type
+same precision the forest's jnp twin traverses at). Per-slot type
 masks are float 0/1, so gathers and the class-proximity terms become
 matmuls; the link mask uses a scratch column so swap rows and link rows
 share one fixed-shape scatter.
@@ -39,7 +39,7 @@ from .features import _batch_consts
 from .forest import RegressionForest
 from .problem import Design, NeighborMoves, SystemSpec
 
-META_BACKENDS = ("host", "fused", "fused-pallas")
+META_BACKENDS = ("host", "fused")
 
 
 def check_meta_backend(backend: str | None, *, allow_none: bool = False) -> None:
@@ -224,7 +224,6 @@ def _fused_features(c: dict, base_perm, base_lm, base_scalars,
 
 
 _SCORE_JIT = None
-_FEAT_JIT = None
 
 
 def _score_moves_fn():
@@ -252,28 +251,15 @@ class MetaScorer:
 
     Holds the device-resident spec constants and forest tensors; each
     :meth:`score_moves` call is one XLA dispatch over the whole padded
-    neighborhood. ``backend="fused-pallas"`` routes the
-    normalize→traverse→argmax tail through the Pallas kernel in
-    kernels/stage_fused. Like the forest's pallas backend it runs only
-    through the Pallas interpreter (``interpret=True``, CPU conformance
-    tests) and raises without it; featurization stays jnp either way."""
+    neighborhood (the ``meta_backend="fused"`` path; ``"host"`` is
+    ``stage._meta_greedy_host``)."""
 
-    def __init__(self, spec: SystemSpec, model: RegressionForest, *,
-                 backend: str = "fused", interpret: bool = False):
+    def __init__(self, spec: SystemSpec, model: RegressionForest):
         import jax.numpy as jnp
 
-        check_meta_backend(backend)
-        if backend == "host":
-            raise ValueError("MetaScorer is the device path; use "
-                             "stage._meta_greedy_host for backend='host'")
-        import jax
-
-        global _SCORE_JIT, _FEAT_JIT
+        global _SCORE_JIT
         if _SCORE_JIT is None:
             _SCORE_JIT = _score_moves_fn()
-        if _FEAT_JIT is None:
-            _FEAT_JIT = jax.jit(_fused_features)
-        self._feat_jit = _FEAT_JIT
         self.spec = spec
         self.c, self._h, self._eid, self._e = _fused_consts(spec)
         self._iu0, self._iu1 = self._h["iu0"], self._h["iu1"]
@@ -281,28 +267,6 @@ class MetaScorer:
             (self.depth, self.n_trees, self.n_nodes) = model.jnp_tensors()
         self.xm = jnp.asarray(model._xm.astype(np.float32))
         self.xs = jnp.asarray(model._xs.astype(np.float32))
-        if backend == "fused-pallas" and not interpret:
-            raise ValueError(
-                "meta backend 'fused-pallas' runs only through the Pallas "
-                "interpreter (interpret=True): the TPU compiler cannot "
-                "lower its forest gathers; use 'fused'")
-        self.pallas = backend == "fused-pallas"
-        self.interpret = interpret
-        self._pallas_nodes = None
-        if self.pallas:
-            # the kernel traverses the (T, M) layout (kernels/forest), not
-            # the flat complex packing the jnp tail gathers from.
-            fl = model._flat
-            t, m = fl["feature"].shape
-            child2 = np.empty((t, 2 * m), np.int32)
-            child2[:, 0::2] = fl["left"]
-            child2[:, 1::2] = fl["right"]
-            self._pallas_nodes = (
-                jnp.asarray(fl["threshold"], jnp.float32),
-                jnp.asarray(np.maximum(fl["feature"], 0), jnp.int32),
-                jnp.asarray(child2),
-                jnp.asarray(fl["value"], jnp.float32),
-            )
 
     # ------------------------------------------------------------- encoding
     def _encode(self, moves: NeighborMoves) -> tuple:
@@ -310,11 +274,7 @@ class MetaScorer:
         arrays (identity rows fill the tail)."""
         s = moves.swaps.shape[0]
         b = len(moves)
-        if self.pallas:
-            from ..kernels import stage_fused as _sf
-            pad = -(-max(b, 1) // _sf.BLOCK_B) * _sf.BLOCK_B
-        else:
-            pad = 1 << max(0, (b - 1).bit_length())
+        pad = 1 << max(0, (b - 1).bit_length())
         sa = np.zeros(pad, np.int32)
         sb = np.zeros(pad, np.int32)
         er = np.full(pad, self._e, np.int32)
@@ -366,17 +326,6 @@ class MetaScorer:
         b = len(moves)
         base_perm, base_lm, scalars = self._base_state(moves.base)
         sa, sb, er, ea = self._encode(moves)
-        if self.pallas:
-            from ..kernels import stage_fused as _sf
-
-            feats = self._feat_jit(self.c, base_perm, base_lm, scalars,
-                                   sa, sb, er, ea)
-            vj, j = _sf.score_block_max(
-                *self._pallas_nodes, self.xm.reshape(1, -1),
-                self.xs.reshape(1, -1), feats,
-                np.array([[b]], np.int32), depth=self.depth,
-                interpret=self.interpret)
-            return int(j), float(vj)
         vals = _SCORE_JIT(self.c, self.thrfeat, self.child, self.value,
                           self.xm, self.xs, base_perm, base_lm, scalars,
                           sa, sb, er, ea, depth=self.depth,

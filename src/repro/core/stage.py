@@ -131,13 +131,11 @@ def _meta_greedy(
     the neighborhood stays in move form (problem.NeighborMoves) and
     move-apply → featurize → normalize → forest traversal happen inside a
     single jit (core.fused); only the winning move is materialized.
-    ``"fused-pallas"`` additionally routes the scoring tail through the
-    kernels/stage_fused Pallas kernel (interpreter only: pass a
-    ``scorer`` built with ``interpret=True``); ``"host"`` is the legacy
-    host-featurizing loop. All arms consume the identical rng stream and
-    accept with the same strict ``vals[j] > v_curr + 1e-12`` test, so the
-    accepted-move sequences agree across backends up to f32-vs-f64 forest
-    threshold rounding (pinned by tests/test_fused.py).
+    ``"host"`` is the legacy host-featurizing loop. Both arms consume the
+    identical rng stream and accept with the same strict
+    ``vals[j] > v_curr + 1e-12`` test, so the accepted-move sequences
+    agree across backends up to f32-vs-f64 forest threshold rounding
+    (pinned by tests/test_fused.py).
 
     ``scorer`` reuses an already-built :class:`~repro.core.fused.MetaScorer`
     for this model (the multi-chain driver scores every chain's restart
@@ -147,8 +145,7 @@ def _meta_greedy(
         return _meta_greedy_host(
             spec, model, d_from, rng, n_swaps=n_swaps,
             n_link_moves=n_link_moves, max_steps=max_steps)
-    sc = scorer if scorer is not None else MetaScorer(
-        spec, model, backend=backend)
+    sc = scorer if scorer is not None else MetaScorer(spec, model)
     d_curr = d_from
     v_curr = sc.score_base(d_curr)
     for _ in range(max_steps):
@@ -424,7 +421,7 @@ def stage_batch(
                     # One scorer per refit, shared by every chain's meta
                     # search (device-resident forest tensors transfer
                     # once, not K times).
-                    sc = (MetaScorer(spec, m, backend=meta_backend)
+                    sc = (MetaScorer(spec, m)
                           if meta_backend != "host" else None)
                     new_starts = []
                     for res in results:

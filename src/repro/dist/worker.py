@@ -69,11 +69,11 @@ class ShardDeadlineExceeded(RuntimeError):
     process executor's ``fut.result(timeout=...)`` + pool-kill can, so
     the deadline is enforced *cooperatively*: :func:`_execute_inline`
     arms a monotonic deadline in :data:`_DEADLINE` before dispatching,
-    and the worker wraps its evaluator in :class:`_DeadlineGuard`, which
-    raises this before every evaluation batch once the deadline passes.
-    Every search driver funnels all evaluation through
-    ``Evaluator.batch_aux``, so overrun is bounded by a single batch
-    instead of the rest of the round.
+    and the worker arms :func:`deadline_limit` on its evaluator
+    (``Evaluator.limited``), which raises this before every evaluator
+    dispatch once the deadline passes: ``batch_aux``, ``batch_moves`` and
+    ``edp`` all check it, so overrun is bounded by a single batch instead
+    of the rest of the round.
     """
 
 
@@ -81,49 +81,30 @@ _DEADLINE: contextvars.ContextVar[float | None] = contextvars.ContextVar(
     "repro_dist_shard_deadline", default=None)
 
 
-class _DeadlineGuard:
-    """Evaluator proxy that trips :class:`ShardDeadlineExceeded` once the
-    armed deadline passes. Mirrors the Evaluator surface the same way
-    :class:`repro.noc.api.BudgetedEvaluator` does — everything funnels
-    through ``batch_aux``; reads (``n_evals``/``n_calls``/...) delegate
-    untouched, so wrapping never changes a run that meets its deadline."""
+def deadline_limit(deadline: float):
+    """The check that trips :class:`ShardDeadlineExceeded` once the
+    monotonic clock passes ``deadline``, for ``Evaluator.limited``. It
+    reads no evaluator state, so arming it never changes a run that meets
+    its deadline."""
 
-    def __init__(self, ev, deadline: float):
-        self._ev = ev
-        self._deadline = deadline
-
-    def _check(self) -> None:
+    def check() -> None:
         now = time.monotonic()
-        if now > self._deadline:
+        if now > deadline:
             raise ShardDeadlineExceeded(
-                f"cooperative deadline exceeded {now - self._deadline:.3f}s "
+                f"cooperative deadline exceeded {now - deadline:.3f}s "
                 "before an evaluation batch (in-process executors check the "
                 "shard deadline between evaluator dispatches)")
 
-    def batch_aux(self, designs):
-        if designs:
-            self._check()
-        return self._ev.batch_aux(designs)
-
-    def batch(self, designs):
-        return self.batch_aux(designs)[0]
-
-    def __call__(self, d):
-        return self.batch([d])[0]
-
-    def edp(self, d):
-        self._check()
-        return self._ev.edp(d)
-
-    def __getattr__(self, name: str):
-        return getattr(self._ev, name)
+    return check
 
 
-def deadline_wrap(ev):
-    """Wrap ``ev`` in a :class:`_DeadlineGuard` when a cooperative
-    deadline is armed for this dispatch; identity otherwise."""
+def _deadline_armed(ev):
+    """Arm the cooperative deadline of this dispatch on ``ev`` for the
+    duration of the block; a no-op when none is armed."""
     deadline = _DEADLINE.get()
-    return ev if deadline is None else _DeadlineGuard(ev, deadline)
+    if deadline is None:
+        return contextlib.nullcontext()
+    return ev.limited(deadline_limit(deadline))
 
 
 def check_executor(executor: str) -> None:
@@ -143,8 +124,8 @@ def run_shard(problem_json: dict, budget_json: dict, seed: int,
     histories by it).
 
     Calls :func:`repro.noc.api.run` exactly as a direct registry call
-    would (fresh evaluator, ctx built inside the budget guard) — a W=1
-    shard at the root seed is therefore byte-identical to ``run(problem,
+    would (fresh evaluator, ctx built under the budget) — a W=1 shard at
+    the root seed is therefore byte-identical to ``run(problem,
     "stage_batch", budget)``.
     """
     from repro.noc.api import run
@@ -152,13 +133,12 @@ def run_shard(problem_json: dict, budget_json: dict, seed: int,
     problem = NocProblem.from_json(problem_json)
     budget = dataclasses.replace(Budget.from_json(budget_json),
                                  seed=int(seed))
-    # With a cooperative deadline armed, inject a guarded copy of the
-    # evaluator api.run would have built itself — same fresh evaluator,
-    # every dispatch now also checks the shard deadline.
-    ev = (deadline_wrap(problem.evaluator())
-          if _DEADLINE.get() is not None else None)
-    res = run(problem, "stage_batch", budget=budget, config=config_json,
-              ev=ev)
+    # The same fresh evaluator api.run would have built itself; with a
+    # cooperative deadline armed, every dispatch also checks it.
+    ev = problem.evaluator()
+    with _deadline_armed(ev):
+        res = run(problem, "stage_batch", budget=budget, config=config_json,
+                  ev=ev)
     res.extra["worker_id"] = int(worker_id)
     return res.to_json()
 
@@ -188,7 +168,7 @@ def run_shard_round(problem_json: dict, budget_json: dict, seed: int,
 
     from repro.core.local_search import ParetoSet, SearchHistory
     from repro.core.stage import StageBatchResult, stage_batch
-    from repro.noc.api import (BudgetedEvaluator, BudgetExhausted,
+    from repro.noc.api import (BudgetExhausted, budget_limit,
                                design_from_json, design_to_json)
     from repro.noc.optimizers import StageBatchConfig
 
@@ -208,33 +188,35 @@ def run_shard_round(problem_json: dict, budget_json: dict, seed: int,
             [design_from_json(d) for d in global_json["designs"]],
             np.asarray(global_json["objs"], dtype=np.float64))
 
-    # The guard mirrors api.run's uniform budget enforcement: max_evals
-    # duplicates stage_batch's native loop-top checks (same threshold —
-    # it can only fire when the round budget is pre-spent), but max_calls
-    # has no native check and must be enforced here. A guard trip forfeits
-    # the round's (unfinished) search — the coordinator keeps earlier
-    # rounds and flags the merged run exhausted.
+    # The budget check mirrors api.run's uniform budget enforcement:
+    # max_evals duplicates stage_batch's native loop-top checks (same
+    # threshold — it can only fire when the round budget is pre-spent),
+    # but max_calls has no native check and must be enforced here. A
+    # budget trip forfeits the round's (unfinished) search — the
+    # coordinator keeps earlier rounds and flags the merged run exhausted.
+    # The deadline is armed first, so the budget is checked before it.
     ev = problem.evaluator()
-    guarded = BudgetedEvaluator(deadline_wrap(ev), budget)
     res: StageBatchResult | None = None
     ctx = history = None
     try:
-        ctx = problem.context(guarded)  # mesh anchor: 1 guarded eval
-        history = SearchHistory(ev, ctx)
-        res = stage_batch(
-            problem.spec, problem.traffic_matrix(), n_starts=cfg.n_starts,
-            seed=budget.seed, case=problem.case, iters_max=cfg.iters_max,
-            n_swaps=cfg.n_swaps, n_link_moves=cfg.n_link_moves,
-            max_local_steps=cfg.max_local_steps,
-            forest_kwargs=cfg.forest_kwargs,
-            forest_backend=(cfg.forest_backend
-                            if cfg.forest_backend is not None
-                            else problem.forest_backend),
-            meta_backend=cfg.meta_backend,
-            max_evals=budget.max_evals, ev=guarded, ctx=ctx, history=history,
-            starts=starts, train_init=train_init, global_init=global_init,
-            checkpoint_restarts=True,
-        )
+        with _deadline_armed(ev), ev.limited(budget_limit(ev, budget)):
+            ctx = problem.context(ev)  # mesh anchor: 1 budgeted eval
+            history = SearchHistory(ev, ctx)
+            res = stage_batch(
+                problem.spec, problem.traffic_matrix(),
+                n_starts=cfg.n_starts, seed=budget.seed, case=problem.case,
+                iters_max=cfg.iters_max, n_swaps=cfg.n_swaps,
+                n_link_moves=cfg.n_link_moves,
+                max_local_steps=cfg.max_local_steps,
+                forest_kwargs=cfg.forest_kwargs,
+                forest_backend=(cfg.forest_backend
+                                if cfg.forest_backend is not None
+                                else problem.forest_backend),
+                meta_backend=cfg.meta_backend,
+                max_evals=budget.max_evals, ev=ev, ctx=ctx, history=history,
+                starts=starts, train_init=train_init,
+                global_init=global_init, checkpoint_restarts=True,
+            )
     except BudgetExhausted:
         pass
     exhausted = res is None
@@ -531,7 +513,7 @@ def _execute_inline(fn, arg_tuples, executor, meta, timeout_s, max_retries,
                 if timeout_s is not None and elapsed > timeout_s:
                     # Backstop for shards that overran between evaluator
                     # dispatches (e.g. the final surrogate refit): the
-                    # cooperative guard can only fire at an evaluation.
+                    # cooperative check can only fire at an evaluation.
                     raise _ShardTimeout(
                         f"shard ran {elapsed:.3f}s, deadline {timeout_s}s "
                         "(post-hoc backstop: the overrun fell between "

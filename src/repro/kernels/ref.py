@@ -22,7 +22,7 @@ def walk_accumulate_np(nh, f, delay, *, max_hops: int):
     """Pure-numpy scalar-loop oracle: walk each (src, dst) pair one hop at
     a time exactly as the routing recurrence defines it. Third corner of
     the link-util conformance triangle (numpy / jnp / Pallas-interpret),
-    mirroring minplus/forest."""
+    mirroring minplus."""
     import numpy as np
 
     nh = np.asarray(nh)

@@ -12,9 +12,9 @@ PHV-greedy local search, PCBB) runs through one serializable boundary::
 CLI: ``python -m repro.noc run|compare|agnostic`` (see repro.noc.cli).
 """
 
-from .api import (Budget, BudgetedEvaluator, BudgetExhausted, NocProblem,
-                  RunRecorder, RunResult, design_from_json, design_to_json,
-                  named_spec, run)
+from .api import (Budget, BudgetExhausted, NocProblem, RunRecorder,
+                  RunResult, design_from_json, design_to_json, named_spec,
+                  run)
 from .optimizers import (OPTIMIZERS, AmosaConfig, LocalConfig, Nsga2Config,
                          OptimizerEntry, PcbbConfig, StageBatchConfig,
                          StageConfig, StageDistConfig, get_optimizer,
@@ -25,7 +25,7 @@ from repro.core.agnostic import (OptimizeBudget, optimize_for_traffic,
                                  run_agnostic_study, summarize, thermal_study)
 
 __all__ = [
-    "AmosaConfig", "Budget", "BudgetExhausted", "BudgetedEvaluator",
+    "AmosaConfig", "Budget", "BudgetExhausted",
     "LocalConfig", "NocProblem", "Nsga2Config", "OPTIMIZERS",
     "OptimizeBudget", "OptimizerEntry", "PcbbConfig", "RunRecorder",
     "RunResult", "StageBatchConfig", "StageConfig", "StageDistConfig",

@@ -4,8 +4,9 @@ One serializable boundary for every optimizer in the repo:
 
   * :class:`NocProblem` — spec + traffic + objective case + routing backend.
   * :class:`Budget` — evaluation / dispatch budget + seed, enforced
-    uniformly for every optimizer (the :class:`BudgetedEvaluator` guard
-    backstops drivers that predate per-driver ``max_evals`` support).
+    uniformly for every optimizer (:func:`run` arms it on the evaluator,
+    see :func:`budget_limit`, which backstops drivers that predate
+    per-driver ``max_evals`` support).
   * :class:`RunResult` — Pareto designs + full objective rows, the
     convergence history, eval/dispatch accounting, and optimizer
     diagnostics; JSON ``save``/``load`` round-trips bit-exactly.
@@ -76,9 +77,8 @@ class NocProblem:
     ``case`` selects the objective subset (``repro.core.objectives.CASES``);
     ``backend`` selects the batched-APSP routing backend (core.routing);
     ``forest_backend`` selects the surrogate inference backend for the
-    learning-based optimizers (core.forest.FOREST_BACKENDS — the forest
-    backend triangle, DESIGN.md §4.4; ignored by the non-learning
-    baselines).
+    learning-based optimizers (core.forest.FOREST_BACKENDS, DESIGN.md §4.4;
+    ignored by the non-learning baselines).
 
     Equality/hashing go through the canonical JSON form (the generated
     dataclass ``__eq__`` would crash on ndarray traffic), so problems can
@@ -254,64 +254,35 @@ class Budget:
 
 
 class BudgetExhausted(RuntimeError):
-    """Raised by :class:`BudgetedEvaluator` when a dispatch would start past
-    the budget. :func:`run` catches it and returns the best-so-far result."""
+    """Raised by the :func:`budget_limit` check when a dispatch would start
+    past the budget. :func:`run` catches it and returns the best-so-far
+    result."""
 
 
-class BudgetedEvaluator:
-    """Evaluator proxy enforcing a :class:`Budget` before every dispatch.
+def budget_limit(ev: Evaluator, budget: Budget) -> Callable[[], None]:
+    """The check that enforces ``budget`` on ``ev``, for
+    :meth:`Evaluator.limited`: it raises :class:`BudgetExhausted` before a
+    dispatch once ``ev``'s evaluation or dispatch count has reached it.
 
     Drivers with native ``max_evals`` checks stop themselves at exactly the
-    same threshold, so for them the guard can only fire on their very first
+    same threshold, so for them the check can only fire on their very first
     dispatch (issued before their own loop-top check) — i.e. only when the
     budget was already spent at entry, where an empty result is accurate —
     and never alters a legacy-identical run. It backstops drivers without
     native budget support (e.g. PCBB) and enforces ``max_calls`` uniformly.
     """
 
-    def __init__(self, ev: Evaluator, budget: Budget):
-        self._ev = ev
-        self._budget = budget
-
-    def _check(self) -> None:
-        b = self._budget
-        if b.max_evals is not None and self._ev.n_evals >= b.max_evals:
+    def check() -> None:
+        if budget.max_evals is not None and ev.n_evals >= budget.max_evals:
             raise BudgetExhausted(
-                f"evaluation budget exhausted ({self._ev.n_evals}/"
-                f"{b.max_evals} evals)")
-        if b.max_calls is not None and self._ev.n_calls >= b.max_calls:
+                f"evaluation budget exhausted ({ev.n_evals}/"
+                f"{budget.max_evals} evals)")
+        if budget.max_calls is not None and ev.n_calls >= budget.max_calls:
             raise BudgetExhausted(
-                f"dispatch budget exhausted ({self._ev.n_calls}/"
-                f"{b.max_calls} calls)")
+                f"dispatch budget exhausted ({ev.n_calls}/"
+                f"{budget.max_calls} calls)")
 
-    # Mirror the Evaluator surface; everything funnels through batch_aux.
-    def batch_aux(self, designs: list[Design]):
-        if designs:
-            self._check()
-        return self._ev.batch_aux(designs)
-
-    def batch(self, designs: list[Design]) -> np.ndarray:
-        return self.batch_aux(designs)[0]
-
-    def batch_moves(self, moves) -> np.ndarray:
-        # Must be mirrored here, not left to __getattr__: the raw
-        # evaluator's batch_moves dispatches internally (its delta path
-        # never calls back through this proxy's batch), so delegation
-        # would silently skip the budget check.
-        ms = moves if isinstance(moves, (list, tuple)) else [moves]
-        if any(len(m) for m in ms):
-            self._check()
-        return self._ev.batch_moves(moves)
-
-    def __call__(self, d: Design) -> np.ndarray:
-        return self.batch([d])[0]
-
-    def edp(self, d: Design) -> float:
-        self._check()
-        return self._ev.edp(d)
-
-    def __getattr__(self, name: str):
-        return getattr(self._ev, name)
+    return check
 
 
 # --------------------------------------------------------------------------
@@ -596,7 +567,6 @@ def run(
 
         base_ev = ev if ev is not None else problem.evaluator()
         n_evals0, n_calls0 = base_ev.n_evals, base_ev.n_calls
-        guarded = BudgetedEvaluator(base_ev, budget)
         # The fallback Pareto set is only worth maintaining when the guard can
         # fire with designs already recorded: under a pure max_evals budget,
         # native drivers admit the guard only on their first dispatch (nothing
@@ -611,16 +581,18 @@ def run(
         exhausted = False
         t0 = time.perf_counter()
         try:
-            if ctx is None:
-                # Through the guard: the PHV-anchoring mesh evaluation counts
-                # against (and is forbidden by) a zero budget like any other.
-                ctx = problem.context(guarded)
-            recorder = RunRecorder(base_ev, ctx, callback=callback,
-                                   track_phv=track_phv,
-                                   keep_pareto=guard_can_fire)
-            t0 = time.perf_counter()  # optimizer-only wall clock; setup excluded
-            pareto, extra = entry.run_fn(problem, budget, cfg, guarded, ctx,
-                                         recorder)
+            with base_ev.limited(budget_limit(base_ev, budget)):
+                if ctx is None:
+                    # Under the budget: the PHV-anchoring mesh evaluation
+                    # counts against (and is forbidden by) a zero budget
+                    # like any other.
+                    ctx = problem.context(base_ev)
+                recorder = RunRecorder(base_ev, ctx, callback=callback,
+                                       track_phv=track_phv,
+                                       keep_pareto=guard_can_fire)
+                t0 = time.perf_counter()  # optimizer-only wall clock
+                pareto, extra = entry.run_fn(problem, budget, cfg, base_ev,
+                                             ctx, recorder)
         except BudgetExhausted:
             pareto = recorder.pareto if recorder is not None else ParetoSet.empty()
             extra, exhausted = {}, True

@@ -57,9 +57,8 @@ def _problem_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--backend", default="auto",
                     help="routing backend auto|jnp|pallas (default auto)")
     ap.add_argument("--forest-backend", default="auto",
-                    help="surrogate inference backend auto|numpy|jnp|pallas "
-                         "(default auto; pallas runs only in the Pallas "
-                         "interpreter and is refused here)")
+                    help="surrogate inference backend auto|numpy|jnp "
+                         "(default auto)")
 
 
 def _budget_args(ap: argparse.ArgumentParser) -> None:

@@ -321,19 +321,20 @@ def test_stage_dist_worker_failure_is_survivable(tiny_problem, monkeypatch):
 
 def test_stage_dist_budget_trip_merges_exhausted(tiny_problem):
     """Satellite: a worker that hits its shard budget (the native check or
-    the BudgetedEvaluator guard on max_calls) merges as exhausted=True."""
+    the budget check armed on its evaluator, on max_calls) merges as
+    exhausted=True."""
     res = run(tiny_problem, "stage_dist", budget=Budget(max_evals=60, seed=0),
               config=dict(SMALL, n_workers=2, executor="serial"))
     assert res.exhausted
     assert all(w["exhausted"] for w in res.extra["workers"])
-    # max_calls trips the BudgetedEvaluator guard mid-driver; the worker
+    # max_calls trips the armed budget check mid-driver; the worker
     # returns its best-so-far set and the merge carries the flag.
     res2 = run(tiny_problem, "stage_dist",
                budget=Budget(max_calls=6, seed=0),
                config=dict(SMALL, n_workers=2, executor="serial"))
     assert res2.exhausted and len(res2.designs) >= 1
-    # Synced rounds enforce max_calls too (the guard wraps each round's
-    # evaluator); a tripped round is forfeited but the run completes.
+    # Synced rounds enforce max_calls too (the check is armed on each
+    # round's evaluator); a tripped round is forfeited but the run completes.
     res3 = run(tiny_problem, "stage_dist",
                budget=Budget(max_calls=8, seed=0),
                config=dict(SMALL, n_workers=2, executor="serial",

@@ -78,12 +78,8 @@ def test_backend_validation_and_resolution():
         resolve_forest_backend("bogus")
     assert resolve_forest_backend("numpy") == "numpy"
     assert resolve_forest_backend("jnp") == "jnp"
-    assert resolve_forest_backend("auto", batch=4096) in ("numpy", "jnp",
-                                                          "pallas")
-    # "pallas" is a first-class backend (third leg of the conformance
-    # triangle); its off-TPU fallback is pinned in test_forest_conformance.
-    assert RegressionForest(backend="pallas").backend == "pallas"
-    assert resolve_forest_backend("pallas", interpret=True) == "pallas"
+    assert resolve_forest_backend("auto", batch=4096) in ("numpy", "jnp")
+    assert RegressionForest(backend="jnp").backend == "jnp"
 
 
 def test_single_sample_and_1d_input():

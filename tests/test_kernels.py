@@ -98,22 +98,6 @@ def test_minplus_apsp_interpret_matches_jnp_oracle(n):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
-@pytest.mark.interpret
-def test_forest_kernel_interpret_smoke():
-    """The blocked forest-traversal kernel runs under the interpreter on a
-    multi-block batch (full conformance lives in test_forest_conformance)."""
-    from repro.core.forest import RegressionForest
-
-    rng = np.random.default_rng(0)
-    x = rng.uniform(-1, 1, size=(200, 5))
-    y = x[:, 0] - x[:, 3] + 0.1 * rng.normal(size=200)
-    model = RegressionForest(n_trees=8, max_depth=6, seed=0).fit(x, y)
-    xq = rng.uniform(-1, 1, size=(300, 5))
-    got = model.predict(xq, backend="pallas", interpret=True)
-    np.testing.assert_allclose(got, model.predict(xq, backend="numpy"),
-                               rtol=0, atol=1e-6)
-
-
 def test_minplus_apsp_converges_to_routing_apsp():
     from repro.core import spec_tiny, traffic_matrix
     from repro.core import routing
@@ -163,7 +147,7 @@ def test_walk_accumulate_matches_ref(spec_name):
 
     # third corner of the conformance triangle: the scalar-loop numpy
     # oracle must agree with both the jnp scatter-add port and the kernel
-    # (mirrors the minplus/forest numpy-jnp-pallas triangles).
+    # (mirrors the minplus numpy-jnp-pallas triangle).
     hops_n, dsum_n, util_n, visits_n = ref.walk_accumulate_np(
         nh, fs, c.link_delay, max_hops=c.max_hops
     )

@@ -3,6 +3,7 @@ serialization round trips, the CLI smoke tier, and the hardened move
 validation (real exceptions instead of ``-O``-stripped asserts)."""
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -10,9 +11,11 @@ import pytest
 from repro.core import (CASES, Design, Evaluator, PhvContext, dominates,
                         spec_tiny, traffic_matrix)
 from repro.core.amosa import amosa
-from repro.noc import (Budget, NocProblem, RunResult, design_from_json,
-                       design_to_json, get_optimizer, named_spec,
-                       optimizer_names, run)
+from repro.core.problem import sample_neighbor_moves
+from repro.noc import (Budget, BudgetExhausted, NocProblem, RunResult,
+                       design_from_json, design_to_json, get_optimizer,
+                       named_spec, optimizer_names, run)
+from repro.noc.api import budget_limit
 
 ALL_OPTIMIZERS = ("amosa", "local", "nsga2", "pcbb", "stage", "stage_batch",
                   "stage_dist")
@@ -153,7 +156,11 @@ def test_forest_backend_validated_at_construction():
         StageConfig(forest_backend="bogus")
     with pytest.raises(ValueError, match="forest_backend"):
         StageBatchConfig(forest_backend="bogus")
-    assert StageConfig(forest_backend="pallas").forest_backend == "pallas"
+    with pytest.raises(ValueError, match="forest_backend"):
+        NocProblem(spec=spec_tiny(), traffic="BFS", forest_backend="pallas")
+    with pytest.raises(ValueError, match="meta_backend"):
+        StageBatchConfig(meta_backend="bogus")
+    assert StageConfig(forest_backend="jnp").forest_backend == "jnp"
     assert StageConfig().forest_backend is None  # inherit the problem's
 
 
@@ -296,6 +303,81 @@ def test_budget_guard_max_calls(tiny_problem):
               budget=Budget(max_calls=ev.n_calls + 2, seed=0),
               config=dict(pop_size=8, generations=10), ev=ev, ctx=ctx)
     assert res.exhausted and res.n_calls <= 3
+
+
+# ---------------------------------------------------------------------------
+# Limits armed on the evaluator (budget, shard deadline)
+# ---------------------------------------------------------------------------
+def _armed_limit(kind: str, ev):
+    """(check, exception) for a limit that ``ev`` has already reached."""
+    if kind == "deadline":
+        from repro.dist.worker import ShardDeadlineExceeded, deadline_limit
+
+        return deadline_limit(time.monotonic() - 1.0), ShardDeadlineExceeded
+    budget = (Budget(max_evals=ev.n_evals) if kind == "max_evals"
+              else Budget(max_calls=ev.n_calls))
+    return budget_limit(ev, budget), BudgetExhausted
+
+
+@pytest.mark.parametrize("limit", ["max_evals", "max_calls", "deadline"])
+@pytest.mark.parametrize("dispatch", ["batch_aux", "batch_moves-delta_off",
+                                      "batch_moves-delta_on", "edp"])
+def test_armed_limit_raises_before_dispatch(dispatch, limit):
+    """A limit armed with ``Evaluator.limited`` raises its own exception at
+    every public dispatch entry — ``batch_aux``, ``batch_moves`` on the
+    dense path and on the host-table path, and ``edp`` — before anything
+    is dispatched or a routing table is built; once disarmed, the same call
+    dispatches."""
+    spec = spec_tiny()
+    ev = Evaluator(spec, traffic_matrix(spec, "BFS"),
+                   delta="on" if dispatch.endswith("delta_on") else "off")
+    mesh = spec.mesh_design()
+    ev.batch([mesh])  # the counters a budget at them has reached
+    moves = sample_neighbor_moves(spec, mesh, np.random.default_rng(0),
+                                  n_swaps=2, n_link_moves=2)
+    call = {"batch_aux": lambda: ev.batch_aux([mesh]),
+            "batch_moves-delta_off": lambda: ev.batch_moves(moves),
+            "batch_moves-delta_on": lambda: ev.batch_moves(moves),
+            "edp": lambda: ev.edp(mesh)}[dispatch]
+    check, exc = _armed_limit(limit, ev)
+    n_evals, n_calls = ev.n_evals, ev.n_calls
+    stats = dict(ev.delta_stats)
+    with ev.limited(check):
+        with pytest.raises(exc):
+            call()
+    assert (ev.n_evals, ev.n_calls) == (n_evals, n_calls)
+    assert ev.delta_stats == stats
+    call()
+    assert ev.n_calls == n_calls + 1
+
+
+def test_budget_disarmed_when_run_returns(tiny_problem):
+    """``run`` arms its budget on the evaluator only while it runs: a run
+    that stops on a spent budget leaves the evaluator free for the next
+    run with a larger budget, and for plain calls. Nested limits are
+    checked newest first."""
+    from repro.dist.worker import ShardDeadlineExceeded, deadline_limit
+
+    problem, ev, ctx = tiny_problem
+    mesh = problem.spec.mesh_design()
+    spent = run(problem, "local", budget=Budget(max_evals=ev.n_evals, seed=0),
+                config=SMALL_CONFIGS["local"], ev=ev, ctx=ctx)
+    assert spent.exhausted and spent.n_evals == 0
+    again = run(problem, "local",
+                budget=Budget(max_evals=ev.n_evals + 20, seed=0),
+                config=SMALL_CONFIGS["local"], ev=ev, ctx=ctx)
+    assert again.n_evals > 0
+    n_calls = ev.n_calls
+    ev.batch([mesh])
+    assert ev.n_calls == n_calls + 1
+    with ev.limited(deadline_limit(time.monotonic() - 1.0)):
+        with ev.limited(budget_limit(ev, Budget(max_calls=ev.n_calls))):
+            with pytest.raises(BudgetExhausted):
+                ev.batch([mesh])
+        with pytest.raises(ShardDeadlineExceeded):
+            ev.batch([mesh])
+    ev.batch([mesh])
+    assert ev.n_calls == n_calls + 2
 
 
 def test_run_callback_streams_telemetry(tiny_problem):
