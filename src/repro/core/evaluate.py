@@ -332,8 +332,7 @@ class Evaluator:
             return t
         self.delta_stats["table_misses"] += 1
         with telemetry.span("tables.build", why="miss"):
-            t = routing.host_tables(design_cost_np(self.spec, base.adj),
-                                    self.consts.apsp_iters)
+            t = routing.host_tables(design_cost_np(self.spec, base.adj))
         self._tab_put(key, t)
         return t
 
@@ -348,7 +347,7 @@ class Evaluator:
             cost2[rem[0], rem[1]] = cost2[rem[1], rem[0]] = np.float32(routing.INF)
             cost2[add[0], add[1]] = cost2[add[1], add[0]] = w
             with telemetry.span("tables.build", why="fallback"):
-                return routing.host_tables(cost2, self.consts.apsp_iters)
+                return routing.host_tables(cost2)
         self.delta_stats["delta"] += 1
         return t
 

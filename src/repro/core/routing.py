@@ -281,7 +281,8 @@ def routing_tables_batched(
 # integer far below 2^24 and every f32 sum/min is exact; unreachable entries
 # are exactly INF = 1e9 (itself f32-exact, and 1e9 + small rounds to >= 1e9),
 # so *any* correct shortest-path scheme — device min-plus squaring, host
-# blocked squaring, or bounded Bellman relaxation — lands on the same bits.
+# blocked squaring, Floyd–Warshall, or bounded Bellman relaxation — lands on
+# the same bits.
 
 
 def min_plus_np(a: np.ndarray, b: np.ndarray,
@@ -312,6 +313,19 @@ def apsp_np(cost: np.ndarray, n_iters: int) -> np.ndarray:
         d = d.astype(np.float32)
     for _ in range(n_iters):
         d = min_plus_np(d, d)
+    return d
+
+
+def apsp_fw_np(cost: np.ndarray) -> np.ndarray:
+    """Host APSP by one in-place Floyd–Warshall pass (N³ once, not the
+    squarings' ~N³·log N), dtype-preserving like :func:`apsp_np`. In-place
+    is exact: with a zero diagonal and non-negative weights, row and column
+    ``k`` do not change during step ``k``. On exact inputs (the note
+    above) it lands on :func:`apsp_np`'s bits."""
+    d = np.asarray(cost)
+    d = d.copy() if d.dtype == np.float64 else d.astype(np.float32)
+    for k in range(d.shape[0]):
+        np.minimum(d, d[:, k:k + 1] + d[k:k + 1, :], out=d)
     return d
 
 
@@ -428,19 +442,22 @@ class HostTables(NamedTuple):
                 + self.cost_t.nbytes + self.dist_t.nbytes)
 
 
-def host_tables(cost: np.ndarray, n_iters: int) -> HostTables:
+def host_tables(cost: np.ndarray) -> HostTables:
     """Full host recompute — the delta path's fallback and seed. One f64
-    APSP on the tie-broken costs yields both metrics: dist = floor(dist_t)
-    (exact — see the shadow-metric note above), bit-equal to the f32
-    oracle."""
+    Floyd–Warshall pass on the tie-broken costs yields both metrics: dist =
+    floor(dist_t) (exact — see the shadow-metric note above), bit-equal to
+    the f32 oracle. Next hops come from the edge-list argmin (O(E·N)),
+    bit-equal to :func:`next_hop_np`."""
     cost = np.ascontiguousarray(cost, dtype=np.float32)
     n = cost.shape[0]
     edge = (cost < INF / 2) & ~np.eye(n, dtype=bool)
     cost_t = cost.astype(np.float64)
     cost_t[edge] += _tie_eps(n).astype(np.float64)[edge]
-    dist_t = apsp_np(cost_t, n_iters)
+    dist_t = apsp_fw_np(cost_t)
     dist = np.floor(dist_t).astype(np.float32)
-    return HostTables(cost, dist, next_hop_np(cost, dist), cost_t, dist_t)
+    nh = _nh_cols_sparse(cost, dist, np.arange(n))
+    np.fill_diagonal(nh, np.arange(n))
+    return HostTables(cost, dist, nh, cost_t, dist_t)
 
 
 def delta_link_move(

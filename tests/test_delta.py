@@ -11,6 +11,8 @@ that contract; see DESIGN.md §13 and the notes in core/routing.py.
 Property tests need ``hypothesis``; without it they are skipped and the
 deterministic seeded chains still run (same pattern as test_pareto)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -18,8 +20,8 @@ from repro.core import Evaluator, PhvContext, APP_NAMES
 from repro.core import routing
 from repro.core.local_search import local_search
 from repro.core.objectives import CASES, design_cost_np
-from repro.core.problem import (sample_neighbor_moves, spec_16, spec_large,
-                                spec_tiny)
+from repro.core.problem import (random_design, sample_neighbor_moves,
+                                spec_16, spec_64, spec_large, spec_tiny)
 from repro.core.traffic import avg_traffic
 
 try:
@@ -42,7 +44,7 @@ def _chain_check(spec, seed, steps, *, delta_kw=None, require_delta=True):
     every HostTables field bit-equal to a scratch host_tables rebuild."""
     rng = np.random.default_rng(seed)
     d = spec.mesh_design()
-    t = routing.host_tables(design_cost_np(spec, d.adj), _iters(spec.n_tiles))
+    t = routing.host_tables(design_cost_np(spec, d.adj))
     n_delta = 0
     for _ in range(steps):
         mv = sample_neighbor_moves(spec, d, rng, 0, 4)
@@ -52,8 +54,7 @@ def _chain_check(spec, seed, steps, *, delta_kw=None, require_delta=True):
         t2 = routing.delta_link_move(t, rem, add, _link_weight(spec, add),
                                      **(delta_kw or {}))
         d = mv.materialize(0)
-        ref = routing.host_tables(design_cost_np(spec, d.adj),
-                                  _iters(spec.n_tiles))
+        ref = routing.host_tables(design_cost_np(spec, d.adj))
         if t2 is None:
             t2 = ref
         else:
@@ -67,16 +68,77 @@ def _chain_check(spec, seed, steps, *, delta_kw=None, require_delta=True):
 
 
 # ------------------------------------------------------- oracle parity
-@pytest.mark.parametrize("spec_fn", [spec_tiny, spec_16])
+@pytest.mark.parametrize("spec_fn", [spec_tiny, spec_16, spec_64])
 def test_host_tables_bit_equal_device_oracle(spec_fn):
     spec = spec_fn()
     cost = design_cost_np(spec, spec.mesh_design().adj)
-    t = routing.host_tables(cost, _iters(spec.n_tiles))
+    t = routing.host_tables(cost)
     dist, nh = routing.routing_tables(cost, _iters(spec.n_tiles))
     assert np.array_equal(t.dist, np.asarray(dist))
     assert np.array_equal(t.nh, np.asarray(nh))
     # The shadow metric floors back onto the true f32 distances exactly.
     assert np.array_equal(np.floor(t.dist_t).astype(np.float32), t.dist)
+
+
+def _design_cost(spec_fn, seed):
+    """The mesh's hop costs (``seed`` None) or a seeded random design's."""
+    spec = spec_fn()
+    d = (spec.mesh_design() if seed is None
+         else random_design(spec, np.random.default_rng(seed)))
+    return design_cost_np(spec, d.adj)
+
+
+def _cut_cost(kind):
+    """spec_64 mesh costs with one tile isolated (its row and column INF)
+    or with every edge between slots < 32 and slots >= 32 removed."""
+    cost = _design_cost(spec_64, None).copy()
+    n = cost.shape[0]
+    if kind == "isolated":
+        cost[5, :] = cost[:, 5] = routing.INF
+        cost[5, 5] = 0.0
+    else:
+        lo = np.arange(n) < n // 2
+        cost[np.ix_(lo, ~lo)] = cost[np.ix_(~lo, lo)] = routing.INF
+    return cost
+
+
+ORACLE_CASES = {
+    **{f"{fn.__name__}-{'mesh' if seed is None else f'rand{seed}'}":
+       partial(_design_cost, fn, seed)
+       for fn in (spec_tiny, spec_16, spec_64, spec_large)
+       for seed in (None, 0, 1, 2)},
+    "isolated": partial(_cut_cost, "isolated"),
+    "split": partial(_cut_cost, "split"),
+}
+
+
+@pytest.mark.parametrize("case", list(ORACLE_CASES))
+def test_host_tables_bit_equal_squaring_oracle(case):
+    """host_tables (one Floyd–Warshall pass, edge-list next hop) equals the
+    squaring oracle in every HostTables field, bit for bit: apsp_np on the
+    tie-broken f64 costs, its floor, and the dense next_hop_np. The cut
+    cases run _nh_cols_sparse's dense fallback (an isolated tile) and its
+    INF-tie fix-up (two components, every tile with a neighbor)."""
+    cost = np.asarray(ORACLE_CASES[case](), dtype=np.float32)
+    n = cost.shape[0]
+    edge = (cost < routing.INF / 2) & ~np.eye(n, dtype=bool)
+    if case == "isolated":
+        assert not edge.any(axis=1).all()
+    if case == "split":
+        assert edge.any(axis=1).all()
+    cost_t = cost.astype(np.float64)
+    cost_t[edge] += routing._tie_eps(n).astype(np.float64)[edge]
+    dist_t = routing.apsp_np(cost_t, routing.apsp_iters(n))
+    dist = np.floor(dist_t).astype(np.float32)
+    if case in ("isolated", "split"):
+        assert (dist >= routing.INF).any()
+    ref = routing.HostTables(cost, dist, routing.next_hop_np(cost, dist),
+                             cost_t, dist_t)
+    t = routing.host_tables(cost)
+    for f in ref._fields:
+        got, want = getattr(t, f), getattr(ref, f)
+        assert got.dtype == want.dtype, f
+        assert np.array_equal(got, want), f
 
 
 @pytest.mark.parametrize("spec_fn,seed,steps",
@@ -96,14 +158,13 @@ def test_delta_disconnect_then_reconnect():
     halves instead of falling back."""
     spec = spec_tiny()
     n = spec.n_tiles
-    it = _iters(n)
     # A sparse planar layer: a single chain 0-1, 1-2, 2-3 on layer 0 (the
     # vertical TSVs connect the two layers, so 0-1 is a bridge for pairs
     # split across {0} x {1,2,3} columns of each layer).
     adj = np.zeros((n, n), dtype=bool)
     for a, b in [(0, 1), (1, 2), (2, 3)]:
         adj[a, b] = adj[b, a] = True
-    t = routing.host_tables(design_cost_np(spec, adj), it)
+    t = routing.host_tables(design_cost_np(spec, adj))
     assert np.all(t.dist < routing.INF / 2)  # connected to start
 
     # Move 1: remove (0,1), add (0,2). Mid-move — after the removal phase,
@@ -117,7 +178,7 @@ def test_delta_disconnect_then_reconnect():
     adj1 = adj.copy()
     adj1[0, 1] = adj1[1, 0] = False
     adj1[0, 2] = adj1[2, 0] = True
-    ref1 = routing.host_tables(design_cost_np(spec, adj1), it)
+    ref1 = routing.host_tables(design_cost_np(spec, adj1))
     assert t1 is not None
     for f in ref1._fields:
         assert np.array_equal(getattr(t1, f), getattr(ref1, f)), f
@@ -171,7 +232,7 @@ def test_blocked_device_path_matches_host_above_dense_nmax():
     cost[ii[keep], jj[keep]] = w[keep]
     cost[jj[keep], ii[keep]] = w[keep]
     it = _iters(n)
-    t = routing.host_tables(cost, it)
+    t = routing.host_tables(cost)
     dist = np.asarray(routing.apsp(cost, it))
     assert np.array_equal(dist, t.dist)
     nh = np.asarray(routing.next_hop(cost, dist))
@@ -196,7 +257,7 @@ def test_1024_tile_blocked_apsp_memory_safe():
     cost = design_cost_np(spec, spec.mesh_design().adj)
     it = _iters(spec.n_tiles)
     dist = np.asarray(routing.apsp(cost, it))
-    t = routing.host_tables(cost, it)
+    t = routing.host_tables(cost)
     assert np.array_equal(dist, t.dist)
 
 

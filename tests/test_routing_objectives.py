@@ -124,8 +124,7 @@ def test_early_exit_walk(case):
     c = make_consts(spec)
     f = traffic_matrix(spec, "BP")
     ds = _walk_batch(spec, seed, n_random)
-    tabs = [routing.host_tables(design_cost_np(spec, d.adj), c.apsp_iters)
-            for d in ds]
+    tabs = [routing.host_tables(design_cost_np(spec, d.adj)) for d in ds]
     nhs = [t.nh for t in tabs]
     if stuck is not None:
         nhs[stuck] = _loop_one_pair(nhs[stuck])

@@ -219,8 +219,7 @@ def test_dispatch_spans_carry_the_walks_steps_and_cap(path):
     c = make_consts(spec)
     longest = []
     for d in ds:
-        nh = routing.host_tables(design_cost_np(spec, d.adj),
-                                 c.apsp_iters).nh
+        nh = routing.host_tables(design_cost_np(spec, d.adj)).nh
         h = walk_accumulate_np(nh, np.ones(nh.shape), c.link_delay,
                                max_hops=c.max_hops)[0]
         longest.append(int(h.max()))
